@@ -1,1 +1,1 @@
-from .context import set_mesh, get_mesh, shard_map, axis_size
+from .context import set_mesh, get_mesh

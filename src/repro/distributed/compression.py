@@ -35,8 +35,7 @@ def compress_votes(g, error, axes: Tuple[str, ...]):
     sign = jnp.where(corrected >= 0, 1, -1).astype(jnp.int8)
     # vote count across replicas (Boolean aggregation, Eq 7)
     votes = jax.lax.psum(sign.astype(jnp.int32), axes)
-    from .context import axis_size
-    n = axis_size(axes)  # version-portable replica count (see context.py)
+    n = jax.lax.axis_size(axes)  # replica count over the reduced axes
     decoded = votes.astype(jnp.float32) / n
     scale = jnp.mean(jnp.abs(corrected))          # per-leaf magnitude
     decoded = decoded * scale
@@ -64,8 +63,7 @@ def ef_signsgd_compressed(inner: Optimizer, axes: Tuple[str, ...],
             if e is None:
                 return g, None
             spec = jax.sharding.PartitionSpec(*([None] * g.ndim))
-            from repro.distributed import shard_map
-            dec, new_e = shard_map(
+            dec, new_e = jax.shard_map(
                 lambda gg, ee: compress_votes(gg, ee, axes),
                 mesh=m, in_specs=(spec, spec), out_specs=(spec, spec),
                 check_vma=False)(g, e)

@@ -8,44 +8,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 _MESH = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: newer releases expose it at the
-    top level (with ``check_vma``); older ones only ship
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``).
-
-    Audited against jax 0.4.37 on multi-device CPU meshes
-    (``--xla_force_host_platform_device_count``): that release has NEITHER
-    ``jax.shard_map`` nor ``jax.lax.axis_size``, so the experimental branch
-    here and the ``axis_size`` psum fallback below are the live paths — the
-    serve-path coverage lives in tests/test_mesh_serve.py (the
-    ``multidevice`` marker suite), which tests/test_distributed.py never
-    exercised.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def axis_size(axes):
-    """``jax.lax.axis_size`` across versions (absent before jax 0.4.32-ish):
-    the psum-of-ones fallback is equivalent inside any shard_map body.
-    ``axes`` may be one axis name or a tuple."""
-    if hasattr(jax.lax, "axis_size"):
-        names = axes if isinstance(axes, (tuple, list)) else (axes,)
-        n = 1
-        for a in names:
-            n *= jax.lax.axis_size(a)
-        return n
-    return jax.lax.psum(1, axes)
 
 
 def set_mesh(mesh) -> None:

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _bwd_kernel(x_ref, z_ref, d_ref, o_ref, acc_ref, *, n_b: int,
                 alpha: float):
@@ -52,7 +50,7 @@ def _bwd_kernel(x_ref, z_ref, d_ref, o_ref, acc_ref, *, n_b: int,
 def boolean_weight_bwd(x: jax.Array, z: jax.Array, d: jax.Array, *,
                        alpha: float = 0.0,
                        block_m: int = 256, block_n: int = 256,
-                       block_b: int = 256, interpret: bool = True) -> jax.Array:
+                       block_b: int = 256, interpret: bool = False) -> jax.Array:
     """G_W = Σ_k x[k,:]ᵀ ⊗ (z[k,:]·tanh'(α·d[k,:])).
 
     Args:
@@ -84,7 +82,7 @@ def boolean_weight_bwd(x: jax.Array, z: jax.Array, d: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,8 +13,8 @@ bn/bk multiples of 128 (lane); defaults (256, 256, 512) keep the working set
 x(bm,bk) + w(bk,bn) + acc(bm,bn) = 128K + 128K + 256K ≈ 0.5 MB ≪ 16 MB VMEM
 with headroom for double-buffered pipelines.
 
-Validated on CPU via ``interpret=True`` against ``ref.py``; the TPU path is
-identical code.
+Validated on CPU in the Pallas interpreter against ``ref.py``; the TPU path
+is identical code.
 """
 from __future__ import annotations
 
@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import CompilerParams
 
 
 def _bool_matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
@@ -59,14 +57,14 @@ def _bool_matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int,
 def boolean_matmul(x: jax.Array, w: jax.Array, *,
                    block_m: int = 256, block_n: int = 256, block_k: int = 512,
                    fuse_threshold: bool = False, tau: float = 0.0,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """y = x @ w for ±1 int8 operands; int32 counting output (or fused ±1 int8).
 
     Args:
       x: (M, K) int8 ±1.   w: (K, N) int8 ±1.
       fuse_threshold: emit int8 ±1 = [s >= tau] instead of int32 counts.
-      interpret: run the kernel body in Python (CPU validation). On TPU pass
-        False.
+      interpret: run the kernel body in the Pallas interpreter (CPU);
+        ``ops.boolean_matmul`` decides it from the backend.
     """
     if x.ndim != 2 or w.ndim != 2:
         raise ValueError("boolean_matmul expects 2-D operands")
@@ -96,7 +94,7 @@ def boolean_matmul(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

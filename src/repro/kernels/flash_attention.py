@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   n_k: int, bq: int, bk: int, scale: float, causal: bool,
@@ -86,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 )
 def flash_attention_tpu(q, k, v, *, causal: bool = True, window: int = 0,
                         softcap: float = 0.0, block_q: int = 512,
-                        block_k: int = 512, interpret: bool = True):
+                        block_k: int = 512, interpret: bool = False):
     """q,k,v: (BH, S, hd) -> (BH, S, hd). Causal online-softmax attention."""
     BH, S, hd = q.shape
     bq, bk = min(block_q, S), min(block_k, S)
@@ -115,7 +113,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

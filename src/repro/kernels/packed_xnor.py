@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams, CostEstimate
-
 
 # ---------------------------------------------------------------------------
 # GEMV tile autotune table
@@ -88,8 +86,12 @@ def gemv_tile_config(N: int, Kw: int, dtype=jnp.float32):
 # Packing helpers (pure jnp; used by callers and the reference oracle).
 # Packing layout: bit b of word j along K encodes element k = j*32 + b.
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("axis",))
 def pack_bits(x_pm1: jax.Array, axis: int = -1) -> jax.Array:
-    """Pack a ±1 int8 array into uint32 words along ``axis`` (pad with F)."""
+    """Pack a ±1 int8 array into uint32 words along ``axis`` (pad with F).
+
+    Jitted so the (…, K/32, 32) bit planes fuse into the reduction instead
+    of materializing at 4 bytes per weight (gigabytes at serving widths)."""
     x = jnp.moveaxis(x_pm1, axis, -1)
     K = x.shape[-1]
     Kp = -(-K // 32) * 32
@@ -168,7 +170,7 @@ def _xnor_gemv_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_kw: int):
 def packed_xnor_gemv(x: jax.Array, w_packed: jax.Array, *,
                      k_valid: int,
                      block_n: int = None, block_kw: int = None,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """y[i,j] = Σ_k x[i,k]·e(w[k,j]) with only the weights bit-packed.
 
     Args:
@@ -203,16 +205,13 @@ def packed_xnor_gemv(x: jax.Array, w_packed: jax.Array, *,
     wp = jnp.pad(w_packed, ((0, Kwp - Kw), (0, Np - N)))
 
     kernel = functools.partial(_xnor_gemv_kernel, n_kw=n_kw)
-    # runtimes old enough to lack pl.CostEstimate also predate the
-    # ``cost_estimate`` kwarg itself, so the hint must be omitted from the
-    # call entirely, not passed as None
-    cost_kw = {} if CostEstimate is None else dict(cost_estimate=CostEstimate(
+    cost = pl.CostEstimate(
         # the MAC work after the in-VMEM unpack, and the HBM bytes that
         # actually move: fp32 activations + PACKED weight words + fp32 out
         flops=2 * Mp * Kwp * 32 * Np,
         bytes_accessed=xp.nbytes + wp.nbytes + Mp * Np * 4,
         transcendentals=0,
-    ))
+    )
     yp = pl.pallas_call(
         kernel,
         grid=(Np // bn, n_kw),
@@ -223,11 +222,11 @@ def packed_xnor_gemv(x: jax.Array, w_packed: jax.Array, *,
         out_specs=pl.BlockSpec((Mp, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(xp, wp)
     return yp[:M, :N]
 
@@ -239,7 +238,7 @@ def packed_xnor_gemv(x: jax.Array, w_packed: jax.Array, *,
 def packed_xnor_matmul(x_packed: jax.Array, w_packed: jax.Array, *,
                        k_valid: int,
                        block_m: int = 128, block_n: int = 128,
-                       block_kw: int = 64, interpret: bool = True) -> jax.Array:
+                       block_kw: int = 64, interpret: bool = False) -> jax.Array:
     """y[i,j] = Σ_k e(x[i,k])·e(w[k,j]) from bit-packed operands.
 
     Args:
@@ -270,7 +269,7 @@ def packed_xnor_matmul(x_packed: jax.Array, w_packed: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

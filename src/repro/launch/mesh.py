@@ -16,12 +16,21 @@ none of the assigned shapes requires PP on a 256-chip v5e pod.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, **kw):
+    """``jax.make_mesh`` with every axis ``Auto``: the model code shards
+    through PartitionSpecs, ``with_sharding_constraint`` and ``shard_map``,
+    which the default ``Explicit`` axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
@@ -32,7 +41,7 @@ def make_local_mesh(model_axis: int = 1):
             f"make_local_mesh: {n} visible devices not divisible by "
             f"model_axis={model_axis} (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N on CPU)")
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return auto_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def make_serve_mesh(n_shards: int = 0):
@@ -52,7 +61,7 @@ def make_serve_mesh(n_shards: int = 0):
             f"make_serve_mesh: asked for {n} shards but only {len(devs)} "
             f"devices are visible (set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} on CPU)")
-    return jax.make_mesh((n,), ("model",), devices=devs[:n])
+    return auto_mesh((n,), ("model",), devices=devs[:n])
 
 
 def mesh_batch_axes(mesh) -> tuple:

@@ -144,11 +144,13 @@ def main():
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
 
     from repro.configs import get_config, get_smoke
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import lm_init
     from repro.serve.engine import ServeEngine
+
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend == "embeddings":
@@ -266,7 +268,7 @@ def main():
                 for _ in range(args.requests)]
 
     if args.stream:
-        from repro.serve import SamplingParams, ShedError
+        from repro.serve import RequestStatus, SamplingParams, ShedError
 
         with engine.session(lanes=args.lanes, page_size=args.page_size,
                             segment=args.segment,
@@ -308,8 +310,14 @@ def main():
                         printed[i] = h.tokens_ready
             dt = time.time() - t0
             total = sum(h.tokens_ready for h in handles if h is not None)
-            for i, h in enumerate(handles):
-                if h is not None and h.error is not None:
+            # shed at submit (None) or left the session other than DONE or
+            # CANCELLED: the run failed, and the exit code says so below
+            abnormal = [i for i, h in enumerate(handles)
+                        if h is None or h.status not in (
+                            RequestStatus.DONE, RequestStatus.CANCELLED)]
+            for i in abnormal:
+                h = handles[i]
+                if h is not None:
                     print(f"[serve] req{i} left abnormally: "
                           f"{h.status.name} ({h.error})")
             if args.audit:
@@ -343,6 +351,9 @@ def main():
         print(f"[serve] stream: {args.requests} requests over {args.lanes} "
               f"lanes in {dt:.2f}s ({total/dt:.1f} tok/s aggregate, "
               f"first tokens after {ttft:.2f}s — no wait for pool drain)")
+        if abnormal:
+            raise SystemExit(f"[serve] {len(abnormal)}/{args.requests} "
+                             "requests did not finish")
         return
 
     if args.continuous:

@@ -52,6 +52,7 @@ def main():
     from repro.core import cosine_schedule, hybrid_optimizer
     from repro.data import make_pipeline
     from repro.distributed import set_mesh
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import (make_local_mesh, make_production_mesh,
                                    mesh_batch_axes)
     from repro.launch.shardings import named
@@ -59,6 +60,7 @@ def main():
     from repro.train.loop import TrainLoop
     from repro.train.step import make_train_step
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.mesh == "local":
         mesh = make_local_mesh(args.model_axis)

@@ -720,8 +720,6 @@ def _decode_shardmap(cfg: ModelConfig, qg, k_new, v_new, cache, pos,
     rep = P(b_ax, None, None, None)
     cache_spec = P(b_ax, seq_axes, None, None)
     scale_spec = P(b_ax, seq_axes, None)
-    from repro.distributed import shard_map
-
     in_specs = (rep, P(b_ax, None, None), P(b_ax, None, None),
                 cache_spec, cache_spec)
     out_specs = (rep, cache_spec, cache_spec)
@@ -730,8 +728,8 @@ def _decode_shardmap(cfg: ModelConfig, qg, k_new, v_new, cache, pos,
         in_specs += (P(b_ax, None), P(b_ax, None), scale_spec, scale_spec)
         out_specs += (scale_spec, scale_spec)
         args += (ks_new, vs_new, cache["k_scale"], cache["v_scale"])
-    res = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_vma=False)(*args)
+    res = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(*args)
     written = {"k": res[1], "v": res[2]}
     if quant:
         written["k_scale"], written["v_scale"] = res[3], res[4]

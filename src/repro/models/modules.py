@@ -124,7 +124,9 @@ class ModelConfig:
     # the unsharded graph exactly; sign() amplifies reassociation ulps
     # into token flips). serve_tp == 1 is bit-identical to the unsharded
     # graph — the TP branches are skipped entirely at trace time.
-    decode_chunk: int = 2048       # flash-decode inner chunk over local seq
+    decode_chunk: int = 512        # flash-decode inner chunk over local seq
+    # (also the paged decode kernel's VMEM staging rows: 512 keeps a
+    # v5e lane at 8 KV heads x hd 128 well inside the scoped VMEM limit)
     ssm_chunk: int = 128           # selective-scan chunk (train/prefill)
     reduce_bf16: bool = False      # bf16 cross-shard matmul partials (§Perf)
     block_grad_barriers: bool = False  # barrier between in-group blocks:

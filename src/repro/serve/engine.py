@@ -241,14 +241,12 @@ class ServeEngine:
             return jax.jit(fn, donate_argnums=(1,))
         from jax.sharding import PartitionSpec as P
 
-        from repro.distributed import shard_map
-
-        sm = shard_map(fn, mesh=self.mesh,
-                       in_specs=(self._param_specs, self._pool_specs)
-                       + tuple([P()] * n_plain),
-                       out_specs=tuple([P()] * (n_outs - 1))
-                       + (self._pool_specs,),
-                       check_vma=False)
+        sm = jax.shard_map(fn, mesh=self.mesh,
+                           in_specs=(self._param_specs, self._pool_specs)
+                           + tuple([P()] * n_plain),
+                           out_specs=tuple([P()] * (n_outs - 1))
+                           + (self._pool_specs,),
+                           check_vma=False)
         return jax.jit(sm, donate_argnums=(1,))
 
     def _get_fn(self, key, build):

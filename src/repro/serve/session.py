@@ -48,6 +48,9 @@ prefix-index corruption quarantines the index (cold admission). A REAL
 dispatch failure after donation loses the pool — ``_contain_pool_loss``
 fails every active request terminally, flushes the index (its pages were
 in the lost bytes), and the next admission starts over on a fresh pool.
+A segment graph that fails to lower or compile is NOT contained: it is
+compiled before the pool moves and its error propagates out of
+``step()``.
 ``audit=True`` cross-checks every allocator refcount and index pin
 against the holders' own books after each step.
 """
@@ -72,6 +75,11 @@ from .sampling import logits_all_finite, sample_tokens
 from .scheduler import (TERMINAL, Request, RequestStatus, SamplingParams,
                         Scheduler)
 from .swap import SwapBridge, SwapManager
+
+
+class _PoolLost(Exception):
+    """A segment dispatch failed after the pool was donated (the original
+    error is ``__cause__``)."""
 
 
 def _default_bucket(S: int, floor: int = 8) -> int:
@@ -783,27 +791,36 @@ class ServeSession:
         return admitted
 
     def _dispatch_segment(self, sampled: bool, kernel_on: bool) -> None:
-        """Trace/fetch the segment graph for the given kernel choice and
+        """Compile/fetch the segment graph for the given kernel choice and
         run it, updating the lane mirrors. The kernel flag is pinned in
         BOTH the compile key and the trace-time override, so the fallback
-        graph is cached under — and only under — its own key."""
+        graph is cached under — and only under — its own key.
+
+        The graph is lowered and compiled BEFORE the pool is taken: an
+        error there is a program error and propagates out of ``step()``
+        with the pool and every request intact. Only a failure of the
+        dispatch itself (after donation) raises ``_PoolLost``."""
         key = ("segment", self._pool_key, self.segment, sampled, kernel_on)
+        self._ensure_pool()
+        lanes = (jnp.asarray(self._bt), jnp.asarray(self._pos),
+                 jnp.asarray(self._cur), jnp.asarray(self._steps),
+                 jnp.asarray(self._temps), jnp.asarray(self._keys))
         sfn = self.engine._get_fn(
             key,
-            lambda: self.engine._build_batch_segment(self.segment, sampled))
+            lambda: self.engine._build_batch_segment(self.segment, sampled)
+            .lower(self.engine.params, self._pool, *lanes).compile())
+        pool = self._take_pool()
         try:
-            toks, cur_d, self._pool = sfn(
-                self.engine.params, self._take_pool(), jnp.asarray(self._bt),
-                jnp.asarray(self._pos), jnp.asarray(self._cur),
-                jnp.asarray(self._steps), jnp.asarray(self._temps),
-                jnp.asarray(self._keys))
-        except Exception:
-            # a fn whose dispatch failed may be poisoned (bad trace, dead
-            # device buffers): evict it so recovery re-traces fresh
+            toks, cur_d, pool = sfn(self.engine.params, pool, *lanes)
+            toks, cur = np.asarray(toks), np.array(cur_d)
+        except Exception as e:
+            # the executable may be poisoned (dead device buffers): evict
+            # it so recovery compiles fresh
             self.engine._fns.pop(key, None)
-            raise
-        self._last_toks = np.asarray(toks)
-        self._cur = np.array(cur_d)     # copy: host mirror stays writable
+            raise _PoolLost(e) from e
+        self._pool = pool
+        self._last_toks = toks
+        self._cur = cur                 # a copy: the host mirror stays writable
         self._pos += self.segment
         self._steps += self.segment
 
@@ -869,7 +886,8 @@ class ServeSession:
         XLA gather graph (``REPRO_PAGED_KERNEL=0`` path) for this segment
         — bitwise-identical tokens, no victim; a REAL dispatch exception
         surfaces after donation and is contained by ``_contain_pool_loss``
-        (the pool is unrecoverable by then)."""
+        (the pool is unrecoverable by then). A graph that fails to lower
+        or compile is not contained: it raises out of ``step()``."""
         if not self.sched.active:
             if self.sched.pending:   # unreachable given check_fits at submit
                 raise RuntimeError("scheduler deadlock: pending requests "
@@ -892,15 +910,15 @@ class ServeSession:
         # and both variants stay cached for a mixed session
         sampled = any(r.params.temperature > 0
                       for r in self.sched.active.values())
-        if self.faults is not None \
-                and self.faults.should_fire("kernel_dispatch"):
-            with paged_kernel_override(False):
-                self._dispatch_segment(sampled, False)
-            return True
         try:
-            self._dispatch_segment(sampled, paged_kernel_enabled())
-        except Exception as e:
-            self._contain_pool_loss(e)
+            if self.faults is not None \
+                    and self.faults.should_fire("kernel_dispatch"):
+                with paged_kernel_override(False):
+                    self._dispatch_segment(sampled, False)
+            else:
+                self._dispatch_segment(sampled, paged_kernel_enabled())
+        except _PoolLost as e:
+            self._contain_pool_loss(e.__cause__)
             return False
         return True
 

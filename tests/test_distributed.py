@@ -36,6 +36,7 @@ def test_pjit_train_step_matches_single_device():
         from repro.configs import get_smoke
         from repro.core import hybrid_optimizer
         from repro.distributed import set_mesh
+        from repro.launch.mesh import auto_mesh
         from repro.launch.shardings import named
         from repro.models import lm_init
         from repro.train.step import make_train_step
@@ -53,7 +54,7 @@ def test_pjit_train_step_matches_single_device():
             state = opt.init(params)
             step = make_train_step(cfg, opt, microbatches=2)
             if shard:
-                mesh = jax.make_mesh((4, 2), ("data", "model"))
+                mesh = auto_mesh((4, 2), ("data", "model"))
                 set_mesh(mesh)
                 sh = named(mesh, specs)
                 params = jax.device_put(params, sh)
@@ -84,6 +85,7 @@ def test_shardmap_flash_decode_matches_local():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_smoke
         from repro.distributed import set_mesh
+        from repro.launch.mesh import auto_mesh
         from repro.launch.shardings import named
         from repro.models import cache_init, lm_decode_step, lm_init
 
@@ -110,7 +112,7 @@ def test_shardmap_flash_decode_matches_local():
             lambda p, c, t: lm_decode_step(cfg0, p, c, t))(pf, cache, tok)
 
         # sharded: cache seq over "model" (4), batch over "data" (2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         set_mesh(mesh)
         cfg = cfg0.scaled(use_sharding_constraints=True,
                           batch_axes=("data",), cache_seq_axes=("model",))
@@ -134,13 +136,13 @@ def test_ef_signsgd_compression_roundtrip():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compress_votes
+        from repro.launch.mesh import auto_mesh
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         e = jnp.zeros((8, 64), jnp.bfloat16)
 
-        from repro.distributed import shard_map
-        dec, new_e = jax.jit(shard_map(
+        dec, new_e = jax.jit(jax.shard_map(
             lambda gg, ee: compress_votes(gg, ee, ("data",)),
             mesh=mesh, in_specs=(P("data"), P("data")),
             out_specs=(P(None), P("data")), check_vma=False))(g, e)
@@ -188,6 +190,7 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
         from repro.checkpoint import save_pytree, restore_pytree
         from repro.configs import get_smoke
         from repro.distributed import set_mesh
+        from repro.launch.mesh import auto_mesh
         from repro.launch.shardings import named
         from repro.models import lm_init
 
@@ -196,12 +199,12 @@ def test_elastic_restore_across_mesh_sizes(tmp_path):
         params, specs = lm_init(jax.random.PRNGKey(0), cfg)
 
         # phase 1: shard on (2,2), checkpoint
-        mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh1 = auto_mesh((2, 2), ("data", "model"))
         p1 = jax.device_put(params, named(mesh1, specs))
         save_pytree(p1, ckpt, step=3, sync=True)
 
         # phase 2: 'the fleet grew' — restore onto (4,2)
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh2 = auto_mesh((4, 2), ("data", "model"))
         restored, step = restore_pytree(params, ckpt,
                                         shardings=named(mesh2, specs))
         assert step == 3

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps vs pure-jnp oracles
-(interpret=True executes the kernel bodies on CPU)."""
+(the ops wrappers run the kernel bodies in the interpreter on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -188,3 +188,35 @@ def test_flash_attention_kernel_matches_model_flash():
     fused = fused.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(portable),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernel_mode_follows_the_backend(monkeypatch):
+    """The ops wrappers interpret on the CPU backend and compile on a TPU
+    one, decided at call time from ``jax.default_backend()`` — no
+    environment variable takes part."""
+    import inspect
+
+    from repro.kernels import packed_xnor as px
+
+    seen = {}
+
+    def spy(x, w, *, k_valid, **kw):
+        seen.update(kw)
+        return x
+
+    monkeypatch.setattr(px, "packed_xnor_gemv", spy)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")     # no longer read
+    assert jax.default_backend() == "cpu"
+    ops.packed_xnor_gemv(jnp.zeros((1, 32)), jnp.zeros((1, 8), jnp.uint32),
+                         k_valid=32)
+    assert seen["interpret"] is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ops.packed_xnor_gemv(jnp.zeros((1, 32)), jnp.zeros((1, 8), jnp.uint32),
+                         k_valid=32)
+    assert seen["interpret"] is False
+    from repro.kernels import (boolean_bwd, boolean_matmul, flash_attention,
+                               packed_xnor, paged_attention)
+    for mod in (ops, boolean_bwd, boolean_matmul, flash_attention,
+                packed_xnor, paged_attention):
+        src = inspect.getsource(mod)
+        assert "environ" not in src and "interpret: bool = True" not in src

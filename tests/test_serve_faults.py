@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import io_callback
 
 from repro.configs import get_smoke
 from repro.models import lm_init
@@ -264,11 +265,20 @@ def test_queue_overflow_sheds_in_admission_time(engine):
 def test_pool_loss_contains_all_actives_then_recovers(engine, monkeypatch):
     eng, cfg = engine
     prompts = _prompts(cfg, [9, 11])
+    build = eng._build_batch_segment
+
+    def device_lost():
+        raise RuntimeError("device lost")
 
     def broken_builder(segment, sampled):
-        def fn(*a, **k):
-            raise RuntimeError("device lost")
-        return fn
+        # compiles fine; the executable fails while it runs, i.e. after
+        # the pool buffers were donated to it
+        good = build(segment, sampled)
+
+        def fn(params, pool, *lanes):
+            io_callback(device_lost, None)
+            return good(params, pool, *lanes)
+        return jax.jit(fn, donate_argnums=(1,))
 
     # segment=3 gives this test its own compile-cache keys, so the broken
     # builder is what the first decode resolves
@@ -290,6 +300,41 @@ def test_pool_loss_contains_all_actives_then_recovers(engine, monkeypatch):
         assert again.status is RequestStatus.DONE
         np.testing.assert_array_equal(again.tokens_so_far(),
                                       _ref(eng, prompts[0], 6))
+        _assert_drained_clean(sess)
+
+
+def test_segment_lowering_error_raises_out_of_step(engine, monkeypatch):
+    """A segment graph that cannot be lowered (a kernel the compiler
+    refuses, say) is a program error: it propagates out of ``step()``
+    before the pool is donated, and no request is turned into a FAILED
+    pool-loss victim."""
+    eng, cfg = engine
+    prompts = _prompts(cfg, [9, 11])
+
+    def unlowerable(segment, sampled):
+        def fn(params, pool, *lanes):
+            raise NotImplementedError("kernel refused by the compiler")
+        return jax.jit(fn, donate_argnums=(1,))
+
+    # segment=5: a compile-cache key of its own, so the broken builder is
+    # what the first decode resolves
+    with eng.session(lanes=2, page_size=8, segment=5, audit=True,
+                     prefix_cache=False) as sess:
+        hs = [sess.submit(p, SamplingParams(max_tokens=8)) for p in prompts]
+        sess.step()                      # admissions only
+        monkeypatch.setattr(eng, "_build_batch_segment", unlowerable)
+        with pytest.raises(NotImplementedError, match="refused"):
+            sess.step()
+        assert sess.sched.stats["failed"] == 0
+        for h in hs:
+            assert h.status is RequestStatus.DECODING
+            assert h.error is None
+        assert sess._pool is not None    # never donated
+        monkeypatch.undo()
+        sess.run_until_idle()            # the same requests finish intact
+        for h, p in zip(hs, prompts):
+            assert h.status is RequestStatus.DONE
+            np.testing.assert_array_equal(h.tokens_so_far(), _ref(eng, p, 8))
         _assert_drained_clean(sess)
 
 
