@@ -7,8 +7,9 @@ Two kinds of series share one exposition:
     inter-token latency histograms observed by the step driver (the only
     place first-token and segment-arrival times are visible);
   * SERVE-LEVEL series scraped live from ``ServeSession.stats()`` at
-    render time — scheduler lifecycle counters, queue/lane occupancy,
-    pool-page occupancy, and the prefix-cache counters. These are never
+    render time — scheduler lifecycle counters, queue wait, queue/lane
+    occupancy, pool-page occupancy, programs built, and the prefix-cache
+    counters. These are never
     duplicated into gateway state: the session's own books are the single
     source of truth, and ``render()`` just reads them.
 
@@ -284,6 +285,19 @@ class GatewayMetrics:
                 ("quota_rejections", "Sheds caused by per-tenant quotas")):
             out += _counter(f"serve_sched_{key}_total", help_,
                             sched.get(key, 0))
+        out += _counter("serve_queue_wait_ms_total",
+                        "Milliseconds admitted requests waited in the queue "
+                        "before their admission began",
+                        sched.get("queue_wait_ms", 0.0))
+        out += _counter("serve_queue_waited_total",
+                        "Admissions counted in serve_queue_wait_ms_total",
+                        sched.get("queue_waited", 0))
+        eng = st.get("engine")
+        if eng is not None:
+            out += _counter("serve_programs_built_total",
+                            "Serve programs built (compile-cache misses): "
+                            "a rise under steady traffic is a recompile",
+                            eng["programs_built"])
         out += _gauge("serve_pending_requests",
                       "Requests queued, not yet admitted", st["pending"])
         out += _gauge("serve_active_requests",

@@ -73,6 +73,7 @@ def boolean_weight_bwd(x: jax.Array, z: jax.Array, d: jax.Array, *,
     kernel = functools.partial(_bwd_kernel, n_b=n_b, alpha=alpha)
     yp = pl.pallas_call(
         kernel,
+        name="boolean_weight_bwd",
         grid=(Mp // bm, Np // bn, n_b),
         in_specs=[
             pl.BlockSpec((bb, bm), lambda i, j, k: (k, i)),

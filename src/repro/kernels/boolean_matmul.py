@@ -86,6 +86,7 @@ def boolean_matmul(x: jax.Array, w: jax.Array, *,
                                fuse_threshold=fuse_threshold, tau=tau)
     yp = pl.pallas_call(
         kernel,
+        name="boolean_matmul",
         grid=(Mp // bm, Np // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

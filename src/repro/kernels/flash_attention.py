@@ -100,6 +100,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, window: int = 0,
         window=window, softcap=softcap, seq_len=S)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention_tpu",
         grid=(BH, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),

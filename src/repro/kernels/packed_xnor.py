@@ -214,6 +214,7 @@ def packed_xnor_gemv(x: jax.Array, w_packed: jax.Array, *,
     )
     yp = pl.pallas_call(
         kernel,
+        name="packed_xnor_gemv",
         grid=(Np // bn, n_kw),
         in_specs=[
             pl.BlockSpec((Mp, bkw * 32), lambda j, k: (0, k)),
@@ -261,6 +262,7 @@ def packed_xnor_matmul(x_packed: jax.Array, w_packed: jax.Array, *,
     kernel = functools.partial(_xnor_kernel, n_kw=n_kw, k_valid=k_valid)
     yp = pl.pallas_call(
         kernel,
+        name="packed_xnor_matmul",
         grid=(Mp // bm, Np // bn, n_kw),
         in_specs=[
             pl.BlockSpec((bm, bkw), lambda i, j, k: (i, k)),

@@ -307,6 +307,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, pos,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((L, KV, R, hd), jnp.float32),
         # sequential: lane 0 zeroes the scratch every later lane reuses
@@ -539,6 +540,7 @@ def paged_prefix_attention(q, k_tail, v_tail, k_pool, v_pool, page_ids,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_prefix_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, T, hd), jnp.float32),
         # sequential: step (0, 0) zeroes the scratch every later step reuses

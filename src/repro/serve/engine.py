@@ -33,6 +33,12 @@ Weight serving modes:
     words stream ONCE per step for the whole lane pool. MoE expert tensors
     stay int8 (they are routed einsums, not proj leaves).
 
+Each session program takes its name in its ``_build_*`` method —
+``serve_prefill``, ``serve_tail_prefill``, ``serve_hit_admit``,
+``serve_decode_segment`` — so a device trace's module line shows
+``jit_serve_decode_segment`` (under the mesh's shard_map too), and each
+Pallas kernel passes its own ``name``.
+
 Optional int8-quantized KV cache (cfg.kv_cache_quant) quantizes at both
 prefill and decode writes with per-(token, head) dynamic scales stored
 alongside the cache rows (models/attention.py: kv_quant).
@@ -198,6 +204,7 @@ class ServeEngine:
         # here keyed by geometry; the next same-key session adopts it
         self._prefix_store = {}
         self._fns = {}      # compile-shape key -> jitted fn (FIFO-bounded)
+        self.programs_built = 0     # _get_fn misses: a recompile shows here
         # (temperature is a TRACED argument, deliberately not a compile key)
         self._prefill = jax.jit(
             lambda p, b, c: lm_prefill(cfg, p, b, cache=c))
@@ -252,13 +259,15 @@ class ServeEngine:
     def _get_fn(self, key, build):
         """Shape-keyed compiled-fn cache, LRU-evicted: a hit refreshes the
         entry so steady traffic (the per-segment decode fn) can't be pushed
-        out by a parade of cold one-off shapes (per-prompt-length prefills)."""
+        out by a parade of cold one-off shapes (per-prompt-length prefills).
+        Each miss builds a program and counts in ``programs_built``."""
         if key in self._fns:
             self._fns[key] = fn = self._fns.pop(key)   # move to MRU end
             return fn
         if len(self._fns) >= self.MAX_COMPILED_FNS:
             self._fns.pop(next(iter(self._fns)))
         self._fns[key] = fn = build()
+        self.programs_built += 1
         return fn
 
     # -- shared plumbing ----------------------------------------------------
@@ -342,7 +351,7 @@ class ServeEngine:
         writes each shard's own KVp slice of the request's pages."""
         cfg = self._serve_cfg
 
-        def fn(params, pool, prompt, length, page_ids, lane):
+        def serve_prefill(params, pool, prompt, length, page_ids, lane):
             logits, pcache = lm_prefill(cfg, params,
                                         self._inputs(params, prompt),
                                         length=length)
@@ -350,7 +359,7 @@ class ServeEngine:
                                   page_ids, page_size, length=length)
             return logits, pool
 
-        return self._shard_serve_fn(fn, n_plain=4, n_outs=2)
+        return self._shard_serve_fn(serve_prefill, n_plain=4, n_outs=2)
 
     def _build_batch_segment(self, segment: int, sampled: bool):
         """jitted fused scan of ``segment`` decode steps over the full lane
@@ -372,7 +381,8 @@ class ServeEngine:
         logits, so every shard carries the same token stream."""
         cfg = self._serve_cfg
 
-        def fn(params, pool, block_table, pos, tok, steps, temps, keys):
+        def serve_decode_segment(params, pool, block_table, pos, tok, steps,
+                                 temps, keys):
             def step(carry, _):
                 tok, pool, pos, steps = carry
                 logits, nc = lm_decode_step_paged(
@@ -387,7 +397,8 @@ class ServeEngine:
                 step, (tok, pool, pos, steps), None, length=segment)
             return toks, tok, pool
 
-        return self._shard_serve_fn(fn, n_plain=6, n_outs=3)
+        return self._shard_serve_fn(serve_decode_segment, n_plain=6,
+                                    n_outs=3)
 
     def _role_ids(self, mixer_is_mamba: bool):
         return [i for i, r in enumerate(block_roles(self.cfg))
@@ -440,15 +451,17 @@ class ServeEngine:
             return logits, pool, end_ssm, snaps
 
         if tail:
-            def fn(params, pool, prompt, length, offset, prefix_ids,
-                   prefix_len, page_ids, lane, ssm_init):
+            def serve_tail_prefill(params, pool, prompt, length, offset,
+                                   prefix_ids, prefix_len, page_ids, lane,
+                                   ssm_init):
                 return run(params, pool, prompt, length, offset, prefix_ids,
                            prefix_len, page_ids, lane, ssm_init)
-        else:
-            def fn(params, pool, prompt, length, page_ids, lane):
-                return run(params, pool, prompt, length, None, None, None,
-                           page_ids, lane, None)
-        return jax.jit(fn, donate_argnums=(1,))
+            return jax.jit(serve_tail_prefill, donate_argnums=(1,))
+
+        def serve_prefill(params, pool, prompt, length, page_ids, lane):
+            return run(params, pool, prompt, length, None, None, None,
+                       page_ids, lane, None)
+        return jax.jit(serve_prefill, donate_argnums=(1,))
 
     def _build_hit_admit(self, fork: bool, has_ssm: bool):
         """jitted exact-hit admission: CoW-fork the record's partially-
@@ -458,7 +471,7 @@ class ServeEngine:
         cfg = self.cfg
         mamba_ids = self._role_ids(True)
 
-        def fn(pool, src, dst, lane, end_ssm):
+        def serve_hit_admit(pool, src, dst, lane, end_ssm):
             if fork:
                 pool = fork_page(cfg, pool, src, dst)
             if has_ssm:
@@ -468,7 +481,7 @@ class ServeEngine:
                         pool[f"b{i}"], end_ssm[f"b{i}"], lane)
             return pool
 
-        return jax.jit(fn, donate_argnums=(0,))
+        return jax.jit(serve_hit_admit, donate_argnums=(0,))
 
     def session(self, *, lanes: int = 4, page_size: int = 16,
                 n_pages: Optional[int] = None, segment: int = 1,

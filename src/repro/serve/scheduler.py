@@ -190,6 +190,7 @@ class Request:
         self.stopped = False          # stop_token hit before max_tokens
         self.seq = -1                 # global submit order (FCFS tiebreak)
         self.deadline: Optional[float] = None   # ABSOLUTE wall ms, or None
+        self.queued_at: Optional[float] = None  # clock ms it last queued
         self.fail_reason: Optional[str] = None  # why SHED/EXPIRED/FAILED
         # preemption counters, split by resume mechanism: a SWAP resume
         # restores the identical page bytes and stays bit-equal to the
@@ -261,7 +262,7 @@ class Scheduler:
                  prefix_cache=None, *, max_pending: Optional[int] = None,
                  tenant_page_quota: Optional[int] = None,
                  tenant_lane_quota: Optional[int] = None, faults=None,
-                 hit_first: bool = True, swap=None):
+                 hit_first: bool = True, swap=None, clock=None):
         if lanes < 1 or n_pages < 2:
             raise ValueError("need >=1 lane and >=2 pages (page 0 is the "
                              "reserved garbage page)")
@@ -292,6 +293,10 @@ class Scheduler:
         # hits back in, and submit accounts BOTH memory tiers. The bridge
         # owns all device work — this core stays jax-free.
         self.swap = swap
+        # ``clock`` (-> ms, the session's) stamps each request as it enters
+        # the queue; the session adds the wait at admission to the
+        # ``queue_wait_ms`` / ``queue_waited`` counters
+        self.clock = clock
         self._seq = 0
         # drained by the session after every scheduling phase:
         self.freed_lanes: List[int] = []   # lanes _release'd since last drain
@@ -299,7 +304,8 @@ class Scheduler:
         self.shed_log: List[Request] = []  # SHED after entering the queue
         self.stats = {"admitted": 0, "shed": 0, "expired": 0, "failed": 0,
                       "preemptions": 0, "preempt_swap": 0,
-                      "preempt_recompute": 0, "quota_rejections": 0}
+                      "preempt_recompute": 0, "quota_rejections": 0,
+                      "queue_wait_ms": 0.0, "queue_waited": 0}
 
     @property
     def free_pages(self):
@@ -324,6 +330,14 @@ class Scheduler:
         if req.swap is not None and self.swap is not None:
             self.swap.discard(req.swap)
             req.swap = None
+
+    def _enqueue(self, req: Request, front: bool = False) -> None:
+        if self.clock is not None:
+            req.queued_at = self.clock()
+        if front:
+            self.pending.appendleft(req)
+        else:
+            self.pending.append(req)
 
     def _shed(self, req: Request, reason: str) -> None:
         self._discard_swap(req)
@@ -401,7 +415,7 @@ class Scheduler:
         req.seq = self._seq
         self._seq += 1
         req.status = RequestStatus.QUEUED
-        self.pending.append(req)
+        self._enqueue(req)
 
     @property
     def idle(self) -> bool:
@@ -706,7 +720,7 @@ class Scheduler:
         else:
             req.preempt_recompute += 1
             self.stats["preempt_recompute"] += 1
-        self.pending.appendleft(req)     # preempted work resumes first
+        self._enqueue(req, front=True)   # preempted work resumes first
         self.stats["preemptions"] += 1
         return req
 

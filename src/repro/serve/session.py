@@ -63,6 +63,7 @@ from typing import Iterator, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import block_roles
 from repro.models.attention import paged_kernel_enabled, paged_kernel_override
@@ -294,7 +295,7 @@ class ServeSession:
                                tenant_page_quota=tenant_page_quota,
                                tenant_lane_quota=tenant_lane_quota,
                                faults=self.faults, hit_first=hit_first,
-                               swap=self._swap)
+                               swap=self._swap, clock=self._clock)
         self.key = _raw_key(key) if key is not None else jax.random.PRNGKey(0)
         self.buckets = tuple(sorted(int(b) for b in buckets)) \
             if buckets else None
@@ -359,7 +360,13 @@ class ServeSession:
         TTFT = prefill latency — first tokens never wait out a decode
         segment. Rounds with nothing to admit decode ONE fused segment over
         the lane pool and drain finished lanes. Returns False (and does
-        nothing) once the session is idle."""
+        nothing) once the session is idle.
+
+        Each phase runs inside a profiler span named ``serve.*``
+        (``serve.schedule``, ``serve.prefill``, ``serve.hit_admit``,
+        ``serve.first_token``, ``serve.segment``, ``serve.segment_wait``,
+        ``serve.drain``); a request's spans carry its ``rid``. A span
+        records only while a profiler session is active and adds no sync."""
         if self._closed:
             raise RuntimeError("session is closed")
         if self.sched.idle:
@@ -374,7 +381,8 @@ class ServeSession:
             if self._admit_and_prefill():
                 pass                         # TTFT: return before decoding
             elif self._decode_segment():
-                self._drain_finished()
+                with TraceAnnotation("serve.drain"):
+                    self._drain_finished()
         if self.audit_mode:
             try:
                 self.audit()
@@ -466,11 +474,15 @@ class ServeSession:
         """One flat host-side snapshot of every serving counter — the
         surface the HTTP gateway's ``/metrics`` endpoint renders into
         Prometheus text (gateway/metrics.py): scheduler lifecycle
-        counters, queue/lane occupancy, pool-page occupancy, and (when
-        enabled) the prefix-cache counters. Pure reads, no device sync."""
+        counters (with ``queue_wait_ms``, the session-clock time admitted
+        requests spent queued, summed over ``queue_waited`` admissions),
+        queue/lane occupancy, pool-page occupancy, the engine's count of
+        programs built, and (when enabled) the prefix-cache counters. Pure
+        reads, no device sync."""
         alloc = self.sched.alloc
         return {
             "sched": dict(self.sched.stats),
+            "engine": {"programs_built": self.engine.programs_built},
             "pending": len(self.sched.pending),
             "active": len(self.sched.active),
             "lanes": self.lanes,
@@ -624,7 +636,16 @@ class ServeSession:
         req.cache_extras = None         # index already holds this prompt
         return rec.logits
 
-    def _admit_prefill(self, req, eff, S: int):
+    def _count_queue_wait(self, req: Request) -> None:
+        """Add the time since ``req`` last entered the queue to
+        ``stats()["sched"]["queue_wait_ms"]``, once per admission: a request
+        admitted behind others in the same step counts their prefills."""
+        if req.queued_at is not None:
+            self.sched.stats["queue_wait_ms"] += self._clock() - req.queued_at
+            self.sched.stats["queue_waited"] += 1
+            req.queued_at = None
+
+    def _admit_prefill(self, req, eff, o: int):
         """Cold / partial-hit admission: prefill ONLY the uncached tail
         through its length bucket (pad to the bucket, true length as a
         traced mask), scatter the masked rows into the request's tail
@@ -634,8 +655,7 @@ class ServeSession:
         offset, the gathered prefix K/V pages, and the boundary SSM state
         through ``lm_prefill`` so the tail is computed exactly as a
         continuation of the cached prefix."""
-        o = req.hit.hit_len if req.hit is not None else 0
-        T = S - o
+        T = int(eff.shape[0]) - o
         o_pages = o // self.page_size
         bucket = self._bucket_len(T, strict=False)
         npp_b = -(-bucket // self.page_size)
@@ -725,25 +745,36 @@ class ServeSession:
         and a budget-1 (or instant stop-token) request finishes without
         ever occupying a decode segment."""
         t0 = self._clock()
-        admitted = self.sched.admit()
-        # reset lanes freed by admission-time preemption/faults BEFORE
-        # arming new lanes — a reset must never clobber a fresh admit
-        for lane in self.sched.drain_freed_lanes():
-            self._reset_lane(lane)
-        for req in self.sched.drain_faulted() + self.sched.drain_shed():
-            self._handles.pop(req.rid, None)
+        with TraceAnnotation("serve.schedule"):
+            admitted = self.sched.admit()
+            # reset lanes freed by admission-time preemption/faults BEFORE
+            # arming new lanes — a reset must never clobber a fresh admit
+            for lane in self.sched.drain_freed_lanes():
+                self._reset_lane(lane)
+            for req in self.sched.drain_faulted() + self.sched.drain_shed():
+                self._handles.pop(req.rid, None)
         for req in admitted:
-            if req.swap is not None and self._resume_swapped(req):
-                # bytes + lane state restored; decode continues exactly
-                # where it stopped — no prefill, no token emitted here
-                continue
+            if req.swap is not None:
+                with TraceAnnotation("serve.hit_admit", rid=req.rid):
+                    self._count_queue_wait(req)
+                    resumed = self._resume_swapped(req)
+                if resumed:
+                    # bytes + lane state restored; decode continues exactly
+                    # where it stopped — no prefill, no token emitted here
+                    continue
             eff = req.effective_prompt
             S = int(eff.shape[0])
             try:
                 if req.hit is not None and req.hit.exact:
-                    logits = self._admit_exact(req, S)
+                    with TraceAnnotation("serve.hit_admit", rid=req.rid):
+                        self._count_queue_wait(req)
+                        logits = self._admit_exact(req, S)
                 else:
-                    logits = self._admit_prefill(req, eff, S)
+                    o = req.hit.hit_len if req.hit is not None else 0
+                    with TraceAnnotation("serve.prefill", rid=req.rid,
+                                         tokens=S - o, hit=o):
+                        self._count_queue_wait(req)
+                        logits = self._admit_prefill(req, eff, o)
             except InjectedFault as e:
                 # fired before the pool was taken (host-side poll), so the
                 # pool is intact: fail ONLY the victim, free its resources
@@ -760,13 +791,14 @@ class ServeSession:
                     self._reset_lane(lane)
                 self._handles.pop(req.rid, None)
                 continue
-            lane_key = self._lane_key(req)
-            e = len(req.emitted)
-            first = sample_tokens(
-                self.cfg, logits[:, -1], req.params.temperature,
-                jnp.asarray(lane_key) if req.params.temperature > 0 else None,
-                e)
-            tok0 = int(first[0, 0])
+            with TraceAnnotation("serve.first_token", rid=req.rid):
+                lane_key = self._lane_key(req)
+                e = len(req.emitted)
+                first = sample_tokens(
+                    self.cfg, logits[:, -1], req.params.temperature,
+                    jnp.asarray(lane_key) if req.params.temperature > 0
+                    else None, e)
+                tok0 = int(first[0, 0])
             lane = req.lane
             self._bt[lane] = 0
             self._bt[lane, :len(req.pages)] = req.pages
@@ -801,28 +833,37 @@ class ServeSession:
         with the pool and every request intact. Only a failure of the
         dispatch itself (after donation) raises ``_PoolLost``."""
         key = ("segment", self._pool_key, self.segment, sampled, kernel_on)
-        self._ensure_pool()
-        lanes = (jnp.asarray(self._bt), jnp.asarray(self._pos),
-                 jnp.asarray(self._cur), jnp.asarray(self._steps),
-                 jnp.asarray(self._temps), jnp.asarray(self._keys))
-        sfn = self.engine._get_fn(
-            key,
-            lambda: self.engine._build_batch_segment(self.segment, sampled)
-            .lower(self.engine.params, self._pool, *lanes).compile())
-        pool = self._take_pool()
-        try:
-            toks, cur_d, pool = sfn(self.engine.params, pool, *lanes)
-            toks, cur = np.asarray(toks), np.array(cur_d)
-        except Exception as e:
-            # the executable may be poisoned (dead device buffers): evict
-            # it so recovery compiles fresh
-            self.engine._fns.pop(key, None)
-            raise _PoolLost(e) from e
+        with TraceAnnotation("serve.segment"):
+            self._ensure_pool()
+            lanes = (jnp.asarray(self._bt), jnp.asarray(self._pos),
+                     jnp.asarray(self._cur), jnp.asarray(self._steps),
+                     jnp.asarray(self._temps), jnp.asarray(self._keys))
+            sfn = self.engine._get_fn(
+                key,
+                lambda: self.engine._build_batch_segment(self.segment,
+                                                         sampled)
+                .lower(self.engine.params, self._pool, *lanes).compile())
+            pool = self._take_pool()
+            try:
+                toks, cur_d, pool = sfn(self.engine.params, pool, *lanes)
+            except Exception as e:
+                raise self._segment_lost(key, e) from e
+        with TraceAnnotation("serve.segment_wait"):
+            try:
+                toks, cur = np.asarray(toks), np.array(cur_d)
+            except Exception as e:
+                raise self._segment_lost(key, e) from e
         self._pool = pool
         self._last_toks = toks
         self._cur = cur                 # a copy: the host mirror stays writable
         self._pos += self.segment
         self._steps += self.segment
+
+    def _segment_lost(self, key, exc: Exception) -> _PoolLost:
+        """The segment's executable may be poisoned (dead device buffers):
+        evict it so recovery compiles fresh."""
+        self.engine._fns.pop(key, None)
+        return _PoolLost(exc)
 
     def _contain_pool_loss(self, exc: Exception) -> None:
         """A dispatch failed AFTER the pool was donated: the buffers are

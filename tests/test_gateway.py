@@ -353,6 +353,13 @@ def test_metrics_exposition():
         # prefix counters present and the second request hit
         assert metrics["serve_prefix_lookups_total"] == 2
         assert metrics["serve_prefix_exact_hits_total"] >= 1
+        # queue wait: both admissions counted, on the session's clock;
+        # programs built: at least the cold prefill and the decode segment,
+        # and the series reads the engine's own count
+        assert metrics["serve_queue_waited_total"] == 2
+        assert metrics["serve_queue_wait_ms_total"] >= 0
+        assert metrics["serve_programs_built_total"] \
+            == engine.programs_built >= 2
         # latency histograms: one TTFT observation per stream, cumulative
         # buckets monotone, +Inf bucket == count
         assert metrics["gateway_ttft_seconds_count"] == 2
