@@ -95,7 +95,9 @@ def _paged_case(key, lanes, n_pages, page, quant, KV=2, R=8, hd=16):
 
     def kernel():
         return ops.paged_flash_decode(
-            q, kp, vp, bt, pos, kss, vss, chunk=cfg.decode_chunk)
+            q, kp[None], vp[None], bt, pos, 0,
+            None if kss is None else kss[None],
+            None if vss is None else vss[None], chunk=cfg.decode_chunk)
 
     def gather():
         k = kp[bt].reshape(lanes, C * page, KV, hd)

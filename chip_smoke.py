@@ -183,8 +183,10 @@ def check_kernels(engine, *, lanes: int = LANES, max_len: int = MAX_LEN,
           ).reshape(lanes, C).astype(jnp.int32)
     pos = jnp.asarray(np.linspace(max_len - 1, 0, lanes).astype(np.int32))
     q = jax.random.normal(next(keys), (lanes, kvp, R, hd), jnp.bfloat16)
-    got = ops.paged_flash_decode(q, kp, vp, bt, pos,
-                                 chunk=cfg.decode_chunk)
+    # the kernel reads layer 1 of a two-layer stack whose other layer
+    # holds the other pool, so a wrong layer index reads wrong rows
+    got = ops.paged_flash_decode(q, jnp.stack([vp, kp]), jnp.stack([kp, vp]),
+                                 bt, pos, 1, chunk=cfg.decode_chunk)
 
     @jax.jit
     def decode_ref(q, kp, vp, bt, pos):

@@ -51,14 +51,15 @@ def flash_attention_tpu(q, k, v, **kw):
     return _fa.flash_attention_tpu(q, k, v, **kw)
 
 
-def paged_flash_decode(q, k_pool, v_pool, block_table, pos,
+def paged_flash_decode(q, k_pool, v_pool, block_table, pos, layer,
                        k_scale=None, v_scale=None, **kw):
-    """Serve decode over a block-table paged pool, pages read in place."""
+    """Serve decode over layer ``layer`` of a stacked block-table paged
+    pool, pages read in place."""
     from . import paged_attention as _pa
 
     kw.setdefault("interpret", interpret_mode())
     return _pa.paged_flash_decode(q, k_pool, v_pool, block_table, pos,
-                                  k_scale, v_scale, **kw)
+                                  layer, k_scale, v_scale, **kw)
 
 
 def paged_prefix_attention(q, k_tail, v_tail, k_pool, v_pool, page_ids,

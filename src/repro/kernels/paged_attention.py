@@ -11,7 +11,8 @@ per step — straight from the pool refs (``pl.ANY``) into VMEM scratch.
 
 Two entry points:
 
-  * ``paged_flash_decode`` — one-token decode over L lanes. Grid is per-lane;
+  * ``paged_flash_decode`` — one-token decode over L lanes, reading one
+    layer of the decode scan's stacked pool by its index. Grid is per-lane;
     the lane's live pages (``ceil((pos+1)/page)``, clamped to the table) are
     staged one softmax chunk at a time; int8 KV rows dequantize in-kernel
     from the per-(token, head) scale pools; garbage-page-0 rows and rows
@@ -137,21 +138,20 @@ def _chunk_rows(chunk: int, S: int, page: int) -> int:
 # ---------------------------------------------------------------------------
 # Decode: one token per lane over the lane's block-table pages
 # ---------------------------------------------------------------------------
-def _decode_kernel(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref, *rest,
-                   page: int, C: int, Cc: int, window: int,
+def _decode_kernel(bt_ref, pos_ref, layer_ref, q_ref, kpool_ref, vpool_ref,
+                   *rest, page: int, C: int, Cc: int, window: int,
                    softcap_val: float, scale: float, quant: bool,
                    interpret: bool):
     if quant:
         kspool_ref, vspool_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf, sems = rest
         bufs = (kbuf, vbuf, ksbuf, vsbuf)
-        pools = (kpool_ref, vpool_ref, kspool_ref, vspool_ref)
     else:
         o_ref, kbuf, vbuf, sems = rest
         bufs = (kbuf, vbuf)
-        pools = (kpool_ref, vpool_ref)
 
     lane = pl.program_id(0)
     pos = pos_ref[lane]
+    layer = layer_ref[0]
     S_loc = C * page
     ppc = Cc // page                     # pages per chunk
     n_all = -(-S_loc // Cc)
@@ -177,9 +177,13 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref, *rest,
 
     def page_copies(first, j):
         pid = bt_ref[lane, first + j]
-        dst = [pl.ds(j * page, page)] * 2 + [j] * 2   # rows; scale rows
-        return _copies([(p.at[pid], b.at[d])
-                        for p, b, d in zip(pools, bufs, dst)], sems)
+        rows = pl.ds(j * page, page)
+        pairs = [(kpool_ref.at[layer, pid], kbuf.at[rows]),
+                 (vpool_ref.at[layer, pid], vbuf.at[rows])]
+        if quant:           # the layer's scales, one row per page
+            pairs += [(kspool_ref.at[pid], ksbuf.at[j]),
+                      (vspool_ref.at[pid], vsbuf.at[j])]
+        return _copies(pairs, sems)
 
     q = [q_ref[0, g].astype(jnp.float32) for g in range(KV)]   # (R, hd)
 
@@ -246,23 +250,30 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, kpool_ref, vpool_ref, *rest,
     jax.jit,
     static_argnames=("window", "softcap_val", "chunk", "interpret"),
 )
-def paged_flash_decode(q, k_pool, v_pool, block_table, pos,
+def paged_flash_decode(q, k_pool, v_pool, block_table, pos, layer,
                        k_scale=None, v_scale=None, *, window: int = 0,
                        softcap_val: float = 0.0, chunk: int = 512,
                        interpret: bool = False):
-    """Flash-decode over a paged pool, pages read in place per lane.
+    """Flash-decode over one layer of a stacked paged pool, pages read in
+    place per lane.
 
     Args:
       q: (L, KV, R, hd) grouped queries (R = GQA group size).
-      k_pool/v_pool: (n_pages, page, KV, hd) pool blocks (cfg.dtype or int8).
+      k_pool/v_pool: (G, n_pages, page, KV, hd) pool blocks of every layer
+        group, stacked (cfg.dtype or int8).
       block_table: (L, C) int32 lane-logical page -> physical page.
       pos: (L,) int32 per-lane positions (new token already written at pos).
-      k_scale/v_scale: (n_pages, page, KV) fp32 per-(token, head) scales,
+      layer: int32 scalar — the group ``g`` whose pages are read. It rides
+        as a scalar-prefetch operand beside the block table and the
+        positions, and every DMA reads ``pool.at[g, page]``: no layer-sized
+        slice of the pool is ever taken, so the decode scan can keep the
+        stacked pool as its carry and write the new rows in place.
+      k_scale/v_scale: (G, n_pages, page, KV) fp32 per-(token, head) scales,
         required iff the pools are int8.
       chunk: softmax chunk rows (``cfg.decode_chunk``) — also the VMEM
         staging size.
 
-    Returns (L, KV, R, hd) fp32 — the block-table gather +
+    Returns (L, KV, R, hd) fp32 — the block-table gather of layer ``g`` +
     ``_flash_decode_local`` reference (bitwise in interpret mode).
 
     HEAD-LOCAL CONTRACT (serve-TP): every shape here comes from the
@@ -274,7 +285,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, pos,
     is what keeps kernel-vs-gather parity shard-by-shard.
     """
     L, KV, R, hd = q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     C = block_table.shape[1]
     Cc = _chunk_rows(chunk, C * page, page)
     quant = k_pool.dtype == jnp.int8
@@ -286,23 +297,27 @@ def paged_flash_decode(q, k_pool, v_pool, block_table, pos,
     scratch = [pltpu.VMEM((Cc, KV, hd), k_pool.dtype),
                pltpu.VMEM((Cc, KV, hd), v_pool.dtype)]
     in_specs = [
-        pl.BlockSpec((1, KV, R, hd), lambda lane, bt, pv: (lane, 0, 0, 0)),
+        pl.BlockSpec((1, KV, R, hd), lambda lane, *_: (lane, 0, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    args = [block_table, pos, q, k_pool, v_pool]
+    args = [block_table, pos, jnp.reshape(layer, (1,)).astype(jnp.int32),
+            q, k_pool, v_pool]
     if quant:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        args += [_page_scales(k_scale), _page_scales(v_scale)]
+        # a (page, KV) scale block is too narrow for a DMA: the layer's
+        # scales are relaid out as one lane-dense row per page (a copy of
+        # that layer's scale pool; the K/V pages stay where they are)
+        args += [_page_scales(k_scale[layer]), _page_scales(v_scale[layer])]
         scratch += [pltpu.VMEM((Cc // page, 1, page * KV), jnp.float32)] * 2
     scratch.append(pltpu.SemaphoreType.DMA((4,)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(L,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, KV, R, hd),
-                               lambda lane, bt, pv: (lane, 0, 0, 0)),
+                               lambda lane, *_: (lane, 0, 0, 0)),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(

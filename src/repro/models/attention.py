@@ -573,22 +573,28 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, *,
 
 
 def attention_decode_paged(cfg: ModelConfig, p, x, cache, block_table, pos,
-                           *, local: bool = False):
+                           layer, *, local: bool = False):
     """One-token decode over a block-table PAGED cache (continuous batching).
 
-    x: (L,1,D) with L scheduler lanes; cache{k,v}: (n_pages, page, KVp, hd)
-    pool blocks (plus per-row scale pools under kv_cache_quant);
-    block_table: (L, C) int32 mapping lane-logical page j -> physical page;
-    pos: (L,) int32 per-lane positions. Logical cache row r of lane l lives
-    at ``pool[block_table[l, r // page], r % page]`` — the write scatters
-    the new token into its (page, offset) cell; the read walks the lane's
-    live pages IN PLACE inside the Pallas flash-decode kernel
-    (kernels/paged_attention.py — O(tokens-attended) pool bytes per step),
-    or, under ``REPRO_PAGED_KERNEL=0``, gathers them back into a contiguous
-    (L, C·page, ...) window and runs the same flash-decode with per-lane
-    position masking (the bitwise-identical XLA reference). Physical page 0
-    is the reserved garbage page: idle/overrun lanes point at it, so their
-    writes never touch pages owned by live requests.
+    x: (L,1,D) with L scheduler lanes; cache{k,v}: (G, n_pages, page, KVp,
+    hd) pool blocks of EVERY layer group, stacked (plus per-row scale pools
+    (G, n_pages, page, KVp) under kv_cache_quant); layer: int32 scalar, the
+    group ``g`` this block belongs to; block_table: (L, C) int32 mapping
+    lane-logical page j -> physical page; pos: (L,) int32 per-lane
+    positions. Logical cache row r of lane l in group g lives at
+    ``pool[g, block_table[l, r // page], r % page]`` — the write scatters
+    the new token into that (g, page, offset) cell of the stacked leaf,
+    which XLA performs in place on the decode scan's carry; the read walks
+    the lane's live pages of layer g IN PLACE inside the Pallas
+    flash-decode kernel (kernels/paged_attention.py — O(tokens-attended)
+    pool bytes per step), or, under ``REPRO_PAGED_KERNEL=0``, gathers them
+    from layer g into a contiguous (L, C·page, ...) window and runs the
+    same flash-decode with per-lane position masking (the
+    bitwise-identical XLA reference). No layer-sized slice of the pool is
+    taken or written back on either path. Physical page 0 is the reserved
+    garbage page: idle/overrun lanes point at it, so their writes never
+    touch pages owned by live requests. Returns (out, the stacked cache
+    with the new rows written).
 
     Under serve-TP (``cfg.serve_tp > 1``, engine mesh mode) this body runs
     inside ``shard_map`` on a 1-D ("model",) mesh: the cache pool leaves
@@ -601,7 +607,7 @@ def attention_decode_paged(cfg: ModelConfig, p, x, cache, block_table, pos,
     B = x.shape[0]
     hd = cfg.head_dim_
     hp, kvp = _tp_heads(cfg)
-    page = cache["k"].shape[1]
+    page = cache["k"].shape[2]
     C = block_table.shape[1]
     q, k_new, v_new = _qkv(cfg, p, x, pos[:, None])
     n_rep = hp // kvp
@@ -626,15 +632,15 @@ def attention_decode_paged(cfg: ModelConfig, p, x, cache, block_table, pos,
     if quant:
         k_new, ks_new = kv_quant(k_new)
         v_new, vs_new = kv_quant(v_new)
-        new_cache["k_scale"] = cache["k_scale"].at[page_id, off].set(
+        new_cache["k_scale"] = cache["k_scale"].at[layer, page_id, off].set(
             ks_new[:, 0])
-        new_cache["v_scale"] = cache["v_scale"].at[page_id, off].set(
+        new_cache["v_scale"] = cache["v_scale"].at[layer, page_id, off].set(
             vs_new[:, 0])
     else:
         k_new = k_new.astype(cache["k"].dtype)
         v_new = v_new.astype(cache["v"].dtype)
-    new_cache["k"] = cache["k"].at[page_id, off].set(k_new[:, 0])
-    new_cache["v"] = cache["v"].at[page_id, off].set(v_new[:, 0])
+    new_cache["k"] = cache["k"].at[layer, page_id, off].set(k_new[:, 0])
+    new_cache["v"] = cache["v"].at[layer, page_id, off].set(v_new[:, 0])
 
     if paged_kernel_enabled():
         # Pallas kernel: the lane's block table is walked in-kernel and only
@@ -643,18 +649,20 @@ def attention_decode_paged(cfg: ModelConfig, p, x, cache, block_table, pos,
         from repro.kernels import ops as kops
 
         out = kops.paged_flash_decode(
-            qg, new_cache["k"], new_cache["v"], block_table, pos,
+            qg, new_cache["k"], new_cache["v"], block_table, pos, layer,
             new_cache.get("k_scale"), new_cache.get("v_scale"),
             window=cfg.sliding_window if local else 0,
             softcap_val=cfg.attn_logit_softcap, chunk=cfg.decode_chunk)
     else:
-        # XLA reference: lane-contiguous (L, C*page, KVp, hd) gather
-        k = new_cache["k"][block_table].reshape(B, C * page, kvp, hd)
-        v = new_cache["v"][block_table].reshape(B, C * page, kvp, hd)
-        ks = (new_cache["k_scale"][block_table].reshape(B, C * page, kvp)
-              if quant else None)
-        vs = (new_cache["v_scale"][block_table].reshape(B, C * page, kvp)
-              if quant else None)
+        # XLA reference: lane-contiguous (L, C*page, KVp, hd) gather of
+        # layer g's pages, one gather from the stacked leaf
+        def lanes(leaf):
+            rows = leaf[layer, block_table]
+            return rows.reshape((B, C * page) + rows.shape[3:])
+
+        k, v = lanes(new_cache["k"]), lanes(new_cache["v"])
+        ks = lanes(new_cache["k_scale"]) if quant else None
+        vs = lanes(new_cache["v_scale"]) if quant else None
         m, l, acc = _flash_decode_local(cfg, qg, k, v, pos, 0, local=local,
                                         k_scale=ks, v_scale=vs)
         out = acc / jnp.maximum(l[..., None], 1e-30)

@@ -223,14 +223,18 @@ def cache_init(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def _apply_block_decode(cfg: ModelConfig, bp, role, bcache, x, pos,
-                        block_table=None):
+                        block_table=None, layer=None):
+    """One block of decode. ``bcache`` is the block's cache for this layer
+    group, except for a paged attention block (``block_table`` given),
+    whose ``bcache`` is the stacked pool of every group, addressed at
+    group ``layer``."""
     h = rmsnorm_apply(bp["norm1"], x)
     if role["mixer"] == "mamba":
         # SSM state is O(1) per lane — lane-indexed directly, never paged.
         mix, new_c = M.mamba_decode(cfg, bp["mamba"], h, bcache)
     elif block_table is not None:
         mix, new_c = A.attention_decode_paged(
-            cfg, bp["attn"], h, bcache, block_table, pos,
+            cfg, bp["attn"], h, bcache, block_table, pos, layer,
             local=(role["mixer"] == "attn_local"))
     else:
         mix, new_c = A.attention_decode(cfg, bp["attn"], h, bcache, pos,
@@ -251,14 +255,20 @@ def _apply_block_decode(cfg: ModelConfig, bp, role, bcache, x, pos,
 def lm_decode_step(cfg: ModelConfig, params, cache, tokens):
     """One-token decode. tokens: (B,1) int32. Returns (logits, new_cache).
 
-    The cache rides the scan as CARRY with in-place indexed updates (not
+    The stacked (n_groups, ...) cache rides the layer scan as CARRY (not
     xs→ys), so the while-loop aliases the donated cache buffers instead of
     double-buffering the multi-GiB KV stack (§Perf: decode-cache-carry).
+    How each block reaches its group ``g`` of the carry depends on what it
+    is:
 
-    A contiguous cache carries a scalar ``pos``; a paged cache (see
-    ``lm_decode_step_paged``) additionally carries a ``block_table`` and a
-    per-lane ``pos`` vector, routing attention through block-table
-    gathers/scatters — same body either way.
+    * a paged attention block (the cache has a ``block_table``: see
+      ``lm_decode_step_paged``) keeps its pool leaves stacked and addresses
+      them at ``g`` — the new rows are scattered into ``(g, page, offset)``
+      in place and the kernel reads layer ``g``'s pages where they lie, so
+      no layer-sized slice of the pool is taken or written back;
+    * every other block (mamba state, and the contiguous cache that
+      carries a scalar ``pos``) slices group ``g`` out of the carry, and
+      writes the whole slice back after the block.
     """
     pos = cache["pos"]
     block_table = cache.get("block_table")
@@ -267,19 +277,20 @@ def lm_decode_step(cfg: ModelConfig, params, cache, tokens):
 
     def body(carry, gparams):
         x, blocks, g = carry
-        gcache = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, g, 0, keepdims=False),
-            blocks)
-        new_gcache = {}
+        blocks = dict(blocks)
         for i, role in enumerate(roles):
-            x, c = _apply_block_decode(cfg, gparams[f"b{i}"], role,
-                                       gcache[f"b{i}"], x, pos,
-                                       block_table=block_table)
-            new_gcache[f"b{i}"] = c
-        blocks = jax.tree.map(
-            lambda full, nc: jax.lax.dynamic_update_index_in_dim(
-                full, nc.astype(full.dtype), g, 0),
-            blocks, new_gcache)
+            bp, b = gparams[f"b{i}"], blocks[f"b{i}"]
+            if block_table is not None and role["mixer"] != "mamba":
+                x, blocks[f"b{i}"] = _apply_block_decode(
+                    cfg, bp, role, b, x, pos, block_table, layer=g)
+                continue
+            c = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, g, 0,
+                                                       keepdims=False), b)
+            x, c = _apply_block_decode(cfg, bp, role, c, x, pos)
+            blocks[f"b{i}"] = jax.tree.map(
+                lambda full, nc: jax.lax.dynamic_update_index_in_dim(
+                    full, nc.astype(full.dtype), g, 0), b, c)
         return (x, blocks, g + 1), None
 
     (h, new_blocks, _), _ = jax.lax.scan(
@@ -299,8 +310,9 @@ def lm_decode_step_paged(cfg: ModelConfig, params, cache, tokens):
     cache: {"blocks": paged pool (serve/paged_cache.py layout),
             "block_table": (L, C) int32, "pos": (L,) int32}; tokens (L,1).
     Identical math to ``lm_decode_step`` per lane, but every lane sits at
-    its own position: attention reads/writes go through block-table
-    gathers/scatters into the shared page pool, SSM state is lane-indexed.
+    its own position: attention writes scatter into the shared page pool
+    at (layer group, page, offset) and reads walk the lane's pages of that
+    group in place; SSM state is lane-indexed.
     The lane count L is the jit-stable batch shape — admission/eviction
     only rewrites the (tiny) block table and pos vector, never the graph.
     """

@@ -1,6 +1,6 @@
 """Decode fast-path validation.
 
-Three layers of evidence, per the PR contract:
+Four layers of evidence:
   1. pack_bits/unpack_bits round-trip (deterministic, no hypothesis needed);
   2. the thin-M packed-XNOR GEMV kernel against the pure-jnp oracles —
      exact counting parity on ±1 inputs, fp32 parity on real inputs;
@@ -9,7 +9,9 @@ Three layers of evidence, per the PR contract:
      serving mode against the int8 path on a dense arch (bit-exact there;
      SSM/MoE archs amplify 1-ulp bf16 reduction-order flips through the
      recurrence/top-k routing, so they get oracle coverage at kernel level
-     instead).
+     instead);
+  4. the paged decode step's structure: its jaxpr takes no layer-sized
+     slice of the stacked KV pool and writes none back.
 """
 import jax
 import jax.numpy as jnp
@@ -20,8 +22,8 @@ from repro.configs import get_smoke
 from repro.core import PackedBool, random_boolean
 from repro.kernels import ops, ref
 from repro.kernels.packed_xnor import pack_bits, unpack_bits
-from repro.models import lm_init
-from repro.serve import ServeEngine, pack_weights
+from repro.models import block_roles, lm_decode_step_paged, lm_init
+from repro.serve import ServeEngine, pack_weights, paged_pool_init
 
 
 # ---------------------------------------------------------------------------
@@ -219,3 +221,55 @@ def test_packed_engine_runs_on_ssm_and_hybrid(arch):
     assert out.shape == (2, 4)
     assert (np.asarray(out) >= 0).all()
     assert (np.asarray(out) < cfg.vocab_size).all()
+
+
+# ---------------------------------------------------------------------------
+# 4. paged decode addresses the stacked pool in place
+# ---------------------------------------------------------------------------
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it (scan and
+    jit bodies, cond branches, kernel bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kernel,quant", [("1", False), ("0", False),
+                                          ("1", True)],
+                         ids=["kernel", "fallback", "kernel-int8"])
+def test_paged_decode_takes_no_layer_slice_of_the_pool(monkeypatch, kernel,
+                                                        quant):
+    """The decode step's layer scan keeps the paged K/V pool stacked in its
+    carry: no ``dynamic_slice`` takes a whole layer of a pool leaf, no
+    ``dynamic_update_slice`` writes one back, and the new rows reach the
+    stacked leaves as scatters (two per attention block: K and V)."""
+    monkeypatch.setenv("REPRO_PAGED_KERNEL", kernel)
+    cfg = get_smoke("gemma2-2b")
+    if quant:
+        cfg = cfg.scaled(kv_cache_quant=True)
+    params, _ = lm_init(jax.random.PRNGKey(0), cfg)
+    lanes, n_pages, page = 4, 9, 4
+    pool = paged_pool_init(cfg, lanes, n_pages, page)
+    cache = {"blocks": pool,
+             "block_table": jnp.zeros((lanes, 3), jnp.int32),
+             "pos": jnp.zeros((lanes,), jnp.int32)}
+    tok = jnp.zeros((lanes, 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, t: lm_decode_step_paged(cfg, p, c, t))(
+            params, cache, tok).jaxpr
+
+    stacked = pool["b0"]["k"].shape            # (G, n_pages, page, KVp, hd)
+    layer = (1,) + stacked[1:]
+    eqns = list(_eqns(jaxpr))
+    slices = [e for e in eqns if e.primitive.name == "dynamic_slice"
+              and tuple(e.params["slice_sizes"]) == layer]
+    updates = [e for e in eqns if e.primitive.name == "dynamic_update_slice"
+               and e.invars[1].aval.shape == layer]
+    assert (len(slices), len(updates)) == (0, 0)
+    scatters = [e for e in eqns if e.primitive.name == "scatter"
+                and e.invars[0].aval.shape == stacked]
+    assert len(scatters) == 2 * len(block_roles(cfg))

@@ -65,26 +65,28 @@ def test_packed_gemv_compiles(one_chip, name):
                                                k_valid=K)
 
 
-def _pools(one_chip, quant: bool):
+def _pools(one_chip, quant: bool, layers=()):
+    """Pool leaves of one layer, or stacked over ``layers`` = (G,)."""
     n_pages = LANES * (MAX_LEN // PAGE) + 1
     dt = jnp.int8 if quant else CFG.dtype
-    pool = jax.ShapeDtypeStruct((n_pages, PAGE, KVP, HD), dt,
+    pool = jax.ShapeDtypeStruct(layers + (n_pages, PAGE, KVP, HD), dt,
                                 sharding=one_chip)
-    scale = jax.ShapeDtypeStruct((n_pages, PAGE, KVP), jnp.float32,
+    scale = jax.ShapeDtypeStruct(layers + (n_pages, PAGE, KVP), jnp.float32,
                                  sharding=one_chip)
     return pool, (scale if quant else None)
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_decode_compiles_at_4096_positions(one_chip, quant):
-    pool, scale = _pools(one_chip, quant)
+    pool, scale = _pools(one_chip, quant, layers=(CFG.n_groups,))
     q = jax.ShapeDtypeStruct((LANES, KVP, HP // KVP, HD), CFG.dtype,
                              sharding=one_chip)
     bt = jax.ShapeDtypeStruct((LANES, MAX_LEN // PAGE), jnp.int32,
                               sharding=one_chip)
     pos = jax.ShapeDtypeStruct((LANES,), jnp.int32, sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_text(
-        paged_flash_decode, q, pool, pool, bt, pos, scale, scale,
+        paged_flash_decode, q, pool, pool, bt, pos, layer, scale, scale,
         chunk=CFG.decode_chunk)
 
 
