@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed and the program's state is freed, a sample of
-the requests the window finished, drawn from the seed and always holding
-the longest, is run through the float32 reference (``reference.py``) over
+the requests the window finished (and, where the mix says so, of those
+still in flight with enough served tokens: ``harness.py``), drawn from the
+seed and always holding the longest, is run through the float32 reference (``reference.py``) over
 its prompt and the tokens the program served. The number compared is the
 widest gap, over every served token of the sample, by which the served
 token's reference logit lies below the reference's best logit at that
@@ -34,9 +35,12 @@ def sample(finished, seed: int, k: int = SAMPLE) -> list:
     return [finished[order[0]]] + [finished[i] for i in pick]
 
 
-def compare(cfg: dict, seed: int, finished, pad_to: int) -> dict:
-    """Numbers compared: {name: (value, "<=" or ">=", limit)}."""
-    arch = R.Arch(cfg)
+def compare(cfg: dict, seed: int, finished, pad_to: int, fam=None,
+            devices=None) -> dict:
+    """Numbers compared: {name: (value, "<=" or ">=", limit)}. ``devices``
+    spreads the sampled requests over the cell's chips (``reference.py``).
+    """
+    arch = R.Arch(cfg, fam)
     reqs = sample(finished, seed, cfg["correct"].get("sample", SAMPLE))
     served = sum(len(t) for _, t in reqs)
     bad = sum(int(np.sum((np.asarray(t) < 0) | (np.asarray(t) >= arch.vocab)))
@@ -44,19 +48,21 @@ def compare(cfg: dict, seed: int, finished, pad_to: int) -> dict:
     out = {"served_tokens_compared": (float(served), ">=", 1.0),
            "tokens_out_of_vocab": (float(bad), "<=", 0.0)}
     if reqs and not bad:
-        gaps = R.served_gaps(arch, seed, reqs, pad_to=pad_to)
+        gaps = R.served_gaps(arch, seed, reqs, pad_to=pad_to,
+                             devices=devices)
         out["max_logit_gap"] = (float(max(g.max() for g in gaps)), "<=",
                                 float(cfg["correct"]["max_logit_gap"]))
     return out
 
 
-def control_gap(cfg: dict, seed: int, finished, pad_to: int) -> float:
+def control_gap(cfg: dict, seed: int, finished, pad_to: int, fam=None,
+                devices=None) -> float:
     """The float8 control's reading on the same sample: the widest gap of
     the token the control puts first at each served position. Run by the
     calibration (``control.py``) and the tests, never by a benchmark run."""
     reqs = sample(finished, seed, cfg["correct"].get("sample", SAMPLE))
-    gaps = R.served_gaps(R.Arch(cfg), seed, reqs, control=True,
-                         pad_to=pad_to)
+    gaps = R.served_gaps(R.Arch(cfg, fam), seed, reqs, control=True,
+                         pad_to=pad_to, devices=devices)
     return float(max(g.max() for g in gaps))
 
 

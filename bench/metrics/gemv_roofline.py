@@ -1,25 +1,19 @@
 """gemv_roofline: share of the packed-XNOR GEMV's roofline reached in the
-decode steps of the traced slice, in percent: the least time the chip
-could take for those calls over the time the kernel took.
+decode steps of the traced slice, in percent: the least time one chip
+could take for its calls over the time the kernel took on it, averaged
+over the chips.
 
 Each call's least time is the larger of its operations over the bf16 peak
 and its bytes over the HBM bandwidth. Bytes are what the algorithm needs
-at the published widths: the packed weight words, the lanes' activations
-in bfloat16, and float32 outputs; padded tiles and padded heads do not
-count, so padding waste shows. Kernel events are found by name."""
-
-import importlib.util
-from pathlib import Path
+at the published widths (the family's ``decode_work``): the packed weight
+words, the lanes' activations in bfloat16, and float32 outputs; padded
+tiles and padded heads do not count, so padding waste shows. On a serve
+mesh of n chips a chip's least time is the step's over n, an ideal split
+of the published work, whatever the program splits or replicates: a
+projection every chip computes whole shows as waste. Kernel events are
+found by name."""
 
 NAMES = ("packed_xnor_gemv",)    # the kernel's instruction in the device trace
-
-
-def _projections(cfg):
-    path = Path(__file__).with_name("decode_mfu.py")
-    spec = importlib.util.spec_from_file_location("_decode_mfu", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.projections(cfg)
 
 
 def call_cost(m: int, k: int, n: int):
@@ -29,12 +23,14 @@ def call_cost(m: int, k: int, n: int):
     return ops, nbytes
 
 
-def step_least_s(cfg: dict, lanes: int, peak: dict) -> float:
-    t = 0.0
-    for k, n in _projections(cfg):
+def step_least_s(work: dict, lanes: int, peak: dict) -> float:
+    """The least time of one decode step's GEMVs on one chip."""
+    per_call = {}                 # calls per token -> least time of one each
+    for k, n, calls in work["projections"]:
         ops, nbytes = call_cost(lanes, k, n)
-        t += max(ops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
-    return t * cfg["num_hidden_layers"]
+        per_call[calls] = per_call.get(calls, 0.0) + max(
+            ops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
+    return sum(t * calls for calls, t in per_call.items())
 
 
 def read(run):
@@ -43,5 +39,7 @@ def read(run):
     if not segs or took <= 0:
         return None
     steps = len(segs) * run.serve["segment"]
-    least = steps * step_least_s(run.config, run.serve["lanes"], run.peak)
+    work = run.family.decode_work(run.config)
+    least = steps * step_least_s(work, run.serve["lanes"],
+                                 run.peak) / run.chips
     return 100.0 * least / took

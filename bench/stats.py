@@ -5,7 +5,9 @@ Every latency is timed from the request's DUE time in the schedule, not
 from when it was submitted, so a stall counts against every request that
 was due behind it. A request due in the window that never produced a
 first token counts with the time it had waited when the run gave up on
-it: never less than it would have been.
+it: never less than it would have been. A closed loop's requests that
+were preloaded during set-up are due before the window (negative times):
+they count for their tokens and gaps inside the window only.
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ def ttfts(records, seconds: float, gave_up: float) -> list:
     """Time to first token of every request due in [0, seconds)."""
     out = []
     for r in records:
-        if r.due >= seconds:
+        if not 0.0 <= r.due < seconds:
             continue
         first = r.times[0] if r.times else gave_up
         out.append(first - r.due)
@@ -34,11 +36,11 @@ def ttfts(records, seconds: float, gave_up: float) -> list:
 
 def gaps(records, seconds: float) -> list:
     """Every gap between successive tokens of a request, as the client
-    saw them, whose later token arrived inside the window."""
+    saw them, whose two tokens arrived inside the window."""
     out = []
     for r in records:
         for a, b in zip(r.times, r.times[1:]):
-            if b < seconds:
+            if 0.0 <= a and b < seconds:
                 out.append(b - a)
     return out
 

@@ -97,3 +97,36 @@ def test_fp8_rounds_to_three_mantissa_bits():
     got = np.asarray(R.fp8(x))
     np.testing.assert_allclose(got, [1.0, 1.0, 1.125, 0.3125, -448.0,
                                      2.0 ** -9, 0.0])
+
+
+@pytest.mark.parametrize("mode", ["f32", "fp8"])
+def test_blocked_attention_equals_unblocked(mode):
+    """Attention over blocks of query rows (how the reference fits a chip
+    at 10,240 positions) gives the hidden states of attention over all
+    rows at once, to the bit."""
+    import family
+
+    cfg = json.loads((DATA / "dense-smoke.json").read_text())
+    whole, blocked = R.Arch(cfg), R.Arch(cfg)
+    blocked.score_bytes = cfg["num_attention_heads"] * 64 * 4 * 8
+    dense = family.load("dense")
+    assert dense.query_rows(64, whole.heads, whole.score_bytes) == 64
+    assert dense.query_rows(64, blocked.heads, blocked.score_bytes) == 8
+    rng = np.random.default_rng(3)
+    seqs = [rng.integers(0, cfg["vocab_size"], n) for n in (50, 17)]
+    a = R.final_hidden(whole, 6, seqs, mode, pad_to=64)
+    b = R.final_hidden(blocked, 6, seqs, mode, pad_to=64)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_query_rows_at_the_cells_sizes():
+    """qwen2.5-14b's 40 heads: 2,048 positions attend in one block, as the
+    reference always has; 10,240 in blocks whose scores fit 1 GiB."""
+    import family
+
+    dense = family.load("dense")
+    assert dense.query_rows(2048, 40, dense.SCORE_BYTES) == 2048
+    rows = dense.query_rows(10240, 40, dense.SCORE_BYTES)
+    assert 10240 % rows == 0 and 40 * rows * 10240 * 4 <= dense.SCORE_BYTES
+    assert rows >= 512

@@ -93,3 +93,72 @@ def test_benchmark_file_shape(bench):
     for w in bench["workloads"]:
         assert (BENCH / "traffic" / f"{w['traffic']}.json").is_file()
         assert w["chips"] in (1, 4)
+
+
+def test_cell_file_lays_its_serve_block_over_the_configuration(bench):
+    long = harness.resolve(bench, "qwen2.5-14b-tp4.long-decode", ROOT)
+    chat = harness.resolve(bench, "qwen2.5-14b.chat-prefix", ROOT)
+    assert long["cell"]["chips"] == 4
+    assert long["config"]["serve"] == {
+        "lanes": 4, "max_len": 9216, "page_size": 16, "segment": 4,
+        "buckets": [5120, 5632, 6144], "prefix_cache": False}
+    assert chat["config"]["serve"]["max_len"] == 2048
+    assert chat["config"]["serve"]["prefix_cache"] is True
+    assert long["config"]["correct"] == chat["config"]["correct"]
+    assert long["family"] is chat["family"]
+    assert long["traffic"]["loop"] == "closed"
+    assert {m["name"] for m in long["end_to_end"]} == {
+        "itl_p95_ms", "tokens_per_s", "itl_p95_ms.mesh", "tokens_per_s.mesh",
+        "peak_hbm_gib", "setup_s"}
+    assert {m["name"] for m in chat["end_to_end"]} == {
+        "ttft_p90_ms", "itl_p95_ms", "tokens_per_s", "peak_hbm_gib",
+        "setup_s"}
+    assert {m["name"] for m in long["per_layer"]} == {
+        "decode_step_ms", "decode_mfu", "gemv_roofline",
+        "paged_decode_roofline", "idle_share", "collective_share"}
+
+
+def test_four_chip_cell_closed_mix_and_cell_block_are_files(tmp_path,
+                                                            bench):
+    """A cell on four chips with a closed mix and its own serve block is
+    new files and an entry."""
+    for d in ("traffic", "cells"):
+        (tmp_path / d).mkdir()
+    mix = {"name": "agents", "loop": "closed", "clients": 8,
+           "preload": False, "shape_seed": 3,
+           "classes": [{"share": 1.0, "prompt": {"uniform": [100, 200]},
+                        "output": {"uniform": [50, 60]}}]}
+    (tmp_path / "traffic" / "agents.json").write_text(json.dumps(mix))
+    (tmp_path / "cells" / "qwen2.5-14b.agents.json").write_text(
+        json.dumps({"serve": {"lanes": 8, "buckets": [128, 256],
+                              "prefix_cache": False}}))
+    b = json.loads(json.dumps(bench))
+    b["workloads"].append({"name": "qwen2.5-14b.agents",
+                           "config": "qwen2.5-14b", "traffic": "agents",
+                           "chips": 4, "why": "t"})
+    spec = harness.resolve(b, "qwen2.5-14b.agents", ROOT,
+                           tmp_path / "traffic", tmp_path / "cells")
+    serve = spec["config"]["serve"]
+    assert serve["lanes"] == 8 and serve["max_len"] == 2048
+    assert serve["buckets"] == [128, 256] and serve["prefix_cache"] is False
+    assert spec["config"]["correct"]["max_logit_gap"] == 0.2
+    assert spec["traffic"] == mix and spec["cell"]["chips"] == 4
+
+
+def test_cell_file_holds_only_a_serve_block(tmp_path, bench):
+    (tmp_path / "qwen2.5-14b.chat-prefix.json").write_text(
+        json.dumps({"correct": {"max_logit_gap": 0.25}}))
+    with pytest.raises(ValueError, match="only"):
+        harness.resolve(bench, "qwen2.5-14b.chat-prefix", ROOT,
+                        cells_dir=tmp_path)
+
+
+def test_cell_files_name_cells_and_chips_are_few(bench):
+    names = {w["name"] for w in bench["workloads"]}
+    for f in (BENCH / "cells").glob("*.json"):
+        assert f.stem in names, f
+        assert set(json.loads(f.read_text())) <= {"why", "serve"}
+    four = [w for w in bench["workloads"] if w["chips"] == 4]
+    assert 1 <= len(four) <= max(1, len(bench["workloads"]) // 2)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", [])) <= names

@@ -130,3 +130,111 @@ def test_recorded_v5e_slice():
                                             "arrival-wait", "outside-spans"}
     top = v.top_ops()
     assert len(top) == 10 and not any(n.startswith("while") for n, _ in top)
+
+
+# -- four chips: each chip's own work over that chip's time ------------------
+
+FOUR = BENCH / "tests" / "data" / "trace-4chip-synthetic.json"
+PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def _four_chip_run(chips=4):
+    import family
+    import harness
+
+    data = json.loads(FOUR.read_text())
+    steps = {int(n): s for n, s in data["steps"].items()}
+    data["devices"] = {d: TR.clip(ev, *data["window"])
+                       for d, ev in data["devices"].items()}
+    cfg = json.loads((BENCH / "configs" / "qwen2.5-14b.json").read_text())
+    serve = dict(cfg["serve"], lanes=16, segment=4)
+    return harness.Run(view=TR.View(data, steps), steps=steps, config=cfg,
+                       serve=serve, peak=PEAK, chips=chips,
+                       family=family.of(cfg))
+
+
+def _read(name, run):
+    import harness
+
+    return harness.reader(name)(run)
+
+
+def _max(ops, nbytes):
+    return max(ops / PEAK["bf16_flops"], nbytes / PEAK["hbm_bytes_per_s"])
+
+
+def test_four_chip_busy_and_kernels_average_over_chips():
+    v = _four_chip_run().view
+    # per step, chip d is busy 10+d + 2 + 1 + 2+d + 5 ms
+    busy = [2 * (20 + 2 * d) for d in range(4)]
+    assert v.busy_in_s("decode") == pytest.approx(sum(busy) / 4 * 1e-3)
+    assert v.kernel_s(("packed_xnor_gemv",), "decode") == \
+        pytest.approx(2 * 11.5e-3)
+    assert v.ops_s(TR.is_collective, "decode") == pytest.approx(
+        2 * (1 + 3.5) * 1e-3)
+    assert TR.is_collective("all-gather-done.3")
+    assert TR.is_collective("all-gather.7") and TR.is_collective("all-reduce")
+    assert not TR.is_collective("all-gather-fusion.2")
+    assert not TR.is_collective("fusion.4")
+
+
+def test_gemv_roofline_counts_each_chips_columns():
+    """A chip's least time is a quarter of the published work, whatever
+    the program splits or replicates, so replication shows as waste."""
+    run = _four_chip_run()
+    d, hd, L, lanes = 5120, 128, 48, 16
+    q, kv, ff = 40 * hd, 8 * hd, 13824
+
+    def cost(k, n):          # ops and needed bytes of one (16, k) x (k, n)
+        return 2.0 * lanes * k * n, k // 32 * n * 4 + lanes * k * 2 \
+            + lanes * n * 4
+
+    whole = [(d, q), (d, kv), (d, kv), (q, d), (d, 2 * ff), (ff, d)]
+    least = 2 * 4 * L * sum(_max(*cost(k, n)) for k, n in whole) / 4
+    got = _read("gemv_roofline", run)
+    assert got == pytest.approx(100 * least / (2 * 11.5e-3), rel=1e-12)
+    assert 0 < got <= 100
+    # the same kernel time on one chip reads four times the share
+    one = _read("gemv_roofline", _four_chip_run(chips=1))
+    assert one == pytest.approx(4 * got, rel=1e-12)
+
+
+def test_paged_decode_roofline_counts_each_chips_kv_heads():
+    run = _four_chip_run()
+    least = 0.0
+    for ctxs in ([1000, 3000], [1004, 3004]):
+        for j in range(4):
+            # a chip holds 2 of the 8 KV heads and 10 of the 40 query heads
+            ops = sum(2.0 * 2 * (c + j) * 10 * 128 for c in ctxs)
+            nbytes = sum(2 * (c + j) * 2 * 128 * 2 + 10 * 128 * 6
+                         for c in ctxs)
+            least += _max(ops, nbytes)
+    least *= 48
+    got = _read("paged_decode_roofline", run)
+    assert got == pytest.approx(100 * least / 4e-3, rel=1e-12)
+    assert 0 < got <= 100
+    # counted as if one chip held all 8 KV heads it would pass 100%
+    assert 4 * got > 100
+
+
+def test_decode_mfu_divides_by_every_chips_time():
+    run = _four_chip_run()
+    d, hd, L, V = 5120, 128, 48, 152064
+    q, kv, ff = 40 * hd, 8 * hd, 13824
+    proj = d * q + 2 * d * kv + q * d + d * 2 * ff + ff * d
+    ops = sum(2.0 * (L * proj + d * V + L * 2 * (c + j) * 40 * hd)
+              for ctxs in ([1000, 3000], [1004, 3004]) for c in ctxs
+              for j in range(4))
+    busy = sum(2 * (20 + 2 * d) for d in range(4)) / 4 * 1e-3
+    got = _read("decode_mfu", run)
+    assert got == pytest.approx(100 * ops / (busy * 197e12 * 4), rel=1e-12)
+    assert 0 < got <= 100
+    one = _read("decode_mfu", _four_chip_run(chips=1))
+    assert one == pytest.approx(4 * got)
+
+
+def test_collective_share_reads_the_all_gather():
+    got = _read("collective_share", _four_chip_run())
+    busy = sum(2 * (20 + 2 * d) for d in range(4)) / 4
+    assert got == pytest.approx(100 * 2 * 4.5 / busy)
+    assert _read("collective_share", _four_chip_run(chips=1)) is None

@@ -23,6 +23,10 @@ import os
 import re
 
 OUR_SPANS = ("trace-window", "arrival-wait", "submit", "step:", "record")
+# collective operations, by base name: ``all-gather``, and the two halves
+# of an asynchronous one, ``all-gather-start`` and ``all-gather-done``
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute", "all-to-all")
 # device lines that hold one event per executed operation
 OP_LINES = ("XLA Ops",)
 
@@ -36,6 +40,13 @@ def op_name(text: str) -> str:
 def base_name(op: str) -> str:
     """``packed_xnor_gemv.82`` -> ``packed_xnor_gemv``."""
     return re.sub(r"(\.\d+)+$", "", op)
+
+
+def is_collective(op: str) -> bool:
+    """A collective operation's event, by its instruction's base name."""
+    base = base_name(op)
+    return any(base == c or base in (c + "-start", c + "-done")
+               for c in COLLECTIVES)
 
 
 def leaves(events) -> list:
@@ -169,12 +180,18 @@ class View:
         """Device time of the events whose base name is one of ``names``,
         inside steps of ``kind`` (all of the window if None), averaged over
         devices."""
+        return self.ops_s(lambda n: base_name(n) in names, kind)
+
+    def ops_s(self, pick, kind: str = None) -> float:
+        """Device time of the events whose name ``pick`` takes, inside
+        steps of ``kind`` (all of the window if None), averaged over
+        devices."""
         spans = self.spans_of(kind) if kind else [(self.lo, self.hi)]
         if not self.devices or not spans:
             return 0.0
         per = []
         for ev in self.devices.values():
-            hits = [(s, s + d) for n, s, d in ev if base_name(n) in names]
+            hits = [(s, s + d) for n, s, d in ev if pick(n)]
             per.append(covered(hits, spans))   # one device runs one op
         return sum(per) / len(per) * 1e-9
 

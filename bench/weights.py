@@ -11,7 +11,9 @@ to a word along the fan-in (bit b of word j is weight j*32+b, 1 is +1):
 each bit is a fair coin, which is the ±1 weight of the paper's random
 init. No int8 tree is ever built. Real leaves (norm scales, biases,
 thresholds, SSM parameters, the bf16 embedding and head) follow the
-recipes in ``RECIPES``, keyed by the last name of the path.
+recipes in ``RECIPES``, keyed by the last name of the path; a family adds
+its own (``family.recipes``). A packed leaf may have axes before its
+``(Kw, N)`` words, such as experts, drawn with the rest of the leaf.
 
 The gated Boolean FFN gets tied weights: the gate and up halves of the
 fused ``wgu`` leaf are the same random words and the threshold ``tau`` is
@@ -48,7 +50,8 @@ def leaf_key(key: jax.Array, path: str) -> jax.Array:
 
 
 def packed_bits(key, shape, k: int) -> jax.Array:
-    """(Kw, N) uint32 words of fair-coin weights; bits past ``k`` are 0."""
+    """(..., Kw, N) uint32 words of fair-coin weights; bits past ``k`` are
+    0."""
     words = jax.random.bits(key, shape, jnp.uint32)
     if k % 32:
         kw = jnp.arange(shape[-2])[:, None]
@@ -99,38 +102,44 @@ RECIPES = {
 TIED = "['wgu']['w']"      # fused [gate | up] columns of the gated FFN
 
 
-def real_leaf(key, path: str, shape, dtype, d_model: int) -> jax.Array:
-    """One real-valued leaf (one layer's slice for a stacked leaf)."""
+def real_leaf(key, path: str, shape, dtype, d_model: int,
+              recipes=None) -> jax.Array:
+    """One real-valued leaf (one layer's slice for a stacked leaf), by the
+    recipe of its last name in ``recipes`` (``RECIPES`` if None)."""
+    recipes = RECIPES if recipes is None else recipes
     name = path.rsplit("['", 1)[-1].rstrip("']")
     if path.startswith("['embed']"):
         return _normal(0.02, dtype)(key, shape)
     if path.startswith("['head']"):
         return _normal(1.0 / math.sqrt(d_model), dtype)(key, shape)
-    if name not in RECIPES:
+    if name not in recipes:
         raise KeyError(f"no weight recipe for leaf {path}")
-    return RECIPES[name](key, shape).astype(dtype)
+    return recipes[name](key, shape).astype(dtype)
 
 
 def layer_leaf(seed_k, path: str, layer, shape, dtype, d_model: int,
-               k: int = 0) -> jax.Array:
+               k: int = 0, recipes=None) -> jax.Array:
     """Layer ``layer`` of a stacked leaf: packed words when ``k`` > 0."""
     key = jax.random.fold_in(leaf_key(seed_k, path), layer)
     if k and path.endswith(TIED):
-        half = packed_bits(key, (shape[0], shape[1] // 2), k)
+        half = packed_bits(key, tuple(shape[:-1]) + (shape[-1] // 2,), k)
         return jnp.concatenate([half, half], axis=-1)
     if k:
         return packed_bits(key, shape, k)
-    return real_leaf(key, path, shape, dtype, d_model)
+    return real_leaf(key, path, shape, dtype, d_model, recipes)
 
 
-def top_leaf(seed_k, path: str, shape, dtype, d_model: int) -> jax.Array:
+def top_leaf(seed_k, path: str, shape, dtype, d_model: int,
+             recipes=None) -> jax.Array:
     """A leaf outside the layer stack (embedding, head, final norm)."""
-    return real_leaf(leaf_key(seed_k, path), path, shape, dtype, d_model)
+    return real_leaf(leaf_key(seed_k, path), path, shape, dtype, d_model,
+                     recipes)
 
 
 def make_stacked(seed_k, path: str, n_layers: int, shape, dtype,
-                 d_model: int, k: int = 0) -> jax.Array:
+                 d_model: int, k: int = 0, recipes=None) -> jax.Array:
     """All layers of a stacked leaf, equal to ``layer_leaf`` layer by
     layer (random draws under vmap match the unbatched ones)."""
     return jax.vmap(lambda l: layer_leaf(seed_k, path, l, shape, dtype,
-                                         d_model, k))(jnp.arange(n_layers))
+                                         d_model, k, recipes)
+                    )(jnp.arange(n_layers))
