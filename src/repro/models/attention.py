@@ -78,8 +78,20 @@ def attention_init(key, cfg: ModelConfig, axis_size: int = 16):
     }
 
 
+def _kv_copies(cfg: ModelConfig, axis_size: int = 16) -> int:
+    """Copies of each KV head that attention groups queries over and the
+    caches hold: 2 for a single KV head (multi-query), else 1. Mosaic
+    moves a bf16 cache block in tiles of 2 along its KV axis, so the paged
+    kernels cannot stage one head's rows; two copies, each read by half of
+    the query heads, do the same arithmetic. (A mesh must split the KV
+    heads evenly, so one KV head only ever serves unsharded.)"""
+    return 2 if cfg.kv_heads_padded(axis_size) == 1 else 1
+
+
 def _tp_heads(cfg: ModelConfig, axis_size: int = 16):
-    """(hp_local, kvp_local): per-device head counts under serve-time TP.
+    """(hp_local, kvp_local): per-device head counts under serve-time TP;
+    kvp_local counts the KV heads attention and the caches hold
+    (``_kv_copies`` of each projected one).
 
     ``serve_tp == 1`` (everything except mesh serving) returns the global
     padded counts unchanged. Under TP the engine shards q/k/v weight
@@ -90,18 +102,24 @@ def _tp_heads(cfg: ModelConfig, axis_size: int = 16):
     """
     hp = cfg.heads_padded(axis_size)
     kvp = cfg.kv_heads_padded(axis_size)
-    return hp // cfg.serve_tp, kvp // cfg.serve_tp
+    return (hp // cfg.serve_tp,
+            kvp // cfg.serve_tp * _kv_copies(cfg, axis_size))
 
 
 def _qkv(cfg: ModelConfig, p, x, positions, axis_size: int = 16):
-    """Project to (B,S,Hp,hd) q and (B,S,KVp,hd) k/v with RoPE applied.
+    """Project to (B,S,Hp,hd) q and (B,S,KVp,hd) k/v with RoPE applied
+    (unless ``cfg.use_rope`` is off: every attention path, prefill, decode,
+    paged decode and the prefix tail, projects here). KVp counts the
+    ``_kv_copies`` of each projected KV head.
 
     Under serve-TP the weights are column-sharded (shard-major for the
     fused wqkv), so the shapes here are the LOCAL head counts.
     """
     B, S, _ = x.shape
     hd = cfg.head_dim_
+    copies = _kv_copies(cfg, axis_size)
     hp, kvp = _tp_heads(cfg, axis_size)
+    kvp //= copies                      # projected KV heads
     if "wqkv" in p:
         # Packed serving layout (pack_weights): q/k/v fused into a single
         # GEMV so the decode token makes ONE pass over the activations and
@@ -115,9 +133,10 @@ def _qkv(cfg: ModelConfig, p, x, positions, axis_size: int = 16):
         q = proj_apply(cfg, p["wq"], x).reshape(B, S, hp, hd)
         k = proj_apply(cfg, p["wk"], x).reshape(B, S, kvp, hd)
         v = proj_apply(cfg, p["wv"], x).reshape(B, S, kvp, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    return q, _repeat_kv(k, copies), _repeat_kv(v, copies)
 
 
 def _head_mask(cfg: ModelConfig, out, axis_size: int = 16):
@@ -357,8 +376,7 @@ def attention_apply(cfg: ModelConfig, p, x, positions, *,
     """Full training/prefill attention block body (no residual/norm)."""
     B, S, _ = x.shape
     hd = cfg.head_dim_
-    hp = cfg.heads_padded(axis_size)
-    kvp = cfg.kv_heads_padded(axis_size)
+    hp, kvp = _tp_heads(cfg, axis_size)
     q, k, v = _qkv(cfg, p, x, positions, axis_size)
     k = _repeat_kv(k, hp // kvp)
     v = _repeat_kv(v, hp // kvp)
@@ -528,8 +546,7 @@ def attention_decode(cfg: ModelConfig, p, x, cache, pos, *,
     """
     B, _, _ = x.shape
     hd = cfg.head_dim_
-    hp = cfg.heads_padded(axis_size)
-    kvp = cfg.kv_heads_padded(axis_size)
+    hp, kvp = _tp_heads(cfg, axis_size)
     positions = jnp.full((B, 1), pos, jnp.int32)
     q, k_new, v_new = _qkv(cfg, p, x, positions, axis_size)
     n_rep = hp // kvp

@@ -331,7 +331,7 @@ def _apply_block_prefill(cfg: ModelConfig, bp, role, x, positions,
                          prefix_pages=None, prefix_ids=None,
                          ssm_init=None, state_at=None):
     """One block of (possibly tail-) prefill. Returns (x, cache entry,
-    snap) — ``snap`` is the mamba page-boundary state snapshots when
+    snap) — ``snap`` is the mamba boundary state snapshots when
     ``state_at`` is set (None otherwise / for attention blocks).
 
     ``prefix`` ({"k"/"v": (1, P, KVp, hd)} fp32, rows valid below
@@ -440,10 +440,10 @@ def lm_prefill(cfg: ModelConfig, params, batch, cache=None,
     from the pool so the gathered rows never materialize (bitwise-identical
     outputs); ``ssm_init`` ({bi: {"h", "conv"}},
     leading G) resumes each mamba recurrence from the prefix-boundary
-    state. ``state_at`` (STATIC position tuple) additionally returns mamba
-    state snapshots at those tail-relative positions — the page-boundary
-    states a finished request donates to the prefix index — as a third
-    result {bi: {"h": (G, B, len(state_at), DI, N), "conv": ...}}.
+    state. ``state_at`` ((n,) int32 tail-relative token counts, traced)
+    additionally returns mamba state snapshots at those positions — the
+    boundary states a finished request donates to the prefix index — as
+    a third result {bi: {"h": (G, B, n, DI, N), "conv": ...}}.
     All four default to None ⇒ the exact cold-prefill graph.
     """
     h = _inputs_to_h(cfg, params, batch)

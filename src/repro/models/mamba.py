@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .modules import (FSDP_AXIS, MODEL_AXIS, ModelConfig, batch_spec,
-                      constrain, fp_weight, fp_zeros, proj_apply, proj_init)
+                      constrain, fp_weight, fp_zeros, proj_apply, proj_init,
+                      rmsnorm_apply)
 
 SSM_CHUNK = 128
 
@@ -50,6 +51,10 @@ def mamba_init(key, cfg: ModelConfig):
         "A_log": (jnp.log(A), P(MODEL_AXIS, None)),
         "D": fp_ones_di(DI),
         "out_proj": proj_init(ks[5], cfg, DI, D, P(MODEL_AXIS, FSDP_AXIS)),
+        **({"dt_norm": (jnp.zeros((R,), jnp.float32), P(None)),
+            "b_norm": (jnp.zeros((N,), jnp.float32), P(None)),
+            "c_norm": (jnp.zeros((N,), jnp.float32), P(None))}
+           if cfg.ssm_norms else {}),
     }
 
 
@@ -75,10 +80,19 @@ def _causal_conv(x, w, b, conv0=None):
 
 
 def _ssm_params(cfg: ModelConfig, p, xc):
-    """xc: (..., DI) conv-activated input -> (dt, Bmat, Cmat)."""
+    """xc: (..., DI) conv-activated input -> (dt, Bmat, Cmat).
+
+    With ``cfg.ssm_norms`` (jamba) the dt rank slice, B and C are each
+    RMS-normed with a learned gain (``dt_norm``/``b_norm``/``c_norm``,
+    applied as ``rmsnorm_apply``'s 1 + scale) before ``dt_proj`` and the
+    scan, in prefill and decode alike."""
     N, R = cfg.ssm_state, cfg.dt_rank_
     dbc = proj_apply(cfg, p["x_proj"], xc)
     dt_r, Bm, Cm = jnp.split(dbc, [R, R + N], axis=-1)
+    if cfg.ssm_norms:
+        dt_r = rmsnorm_apply({"scale": p["dt_norm"]}, dt_r)
+        Bm = rmsnorm_apply({"scale": p["b_norm"]}, Bm)
+        Cm = rmsnorm_apply({"scale": p["c_norm"]}, Cm)
     dt = proj_apply(cfg, p["dt_proj"], dt_r)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))
@@ -164,13 +178,14 @@ def mamba_apply(cfg: ModelConfig, p, x, h0=None, conv0=None,
     boundary state of a cached prefix) — the sequence then continues
     exactly where the prefix left off instead of from zeros.
 
-    ``state_at`` (static tuple of positions, optional): also return
-    ``{"h": (B,len,DI,N), "conv": (B,len,W-1,DI)}`` — the state after
+    ``state_at`` ((n,) int32 token counts, optional, may be traced): also
+    return ``{"h": (B,n,DI,N), "conv": (B,n,W-1,DI)}`` — the state after
     consuming the first ``b`` tokens, for each ``b`` in ``state_at``
-    (prefix-cache page-boundary snapshots). Positions past ``length`` hold
-    the frozen state at ``length`` (the recurrence identity) and garbage
-    conv rows; callers discard them. Free beyond the slices: the
-    per-position states already exist for the output einsum.
+    (prefix-cache boundary snapshots). Positions past ``length`` hold the
+    frozen state at ``length`` (the recurrence identity) and garbage conv
+    rows, and a ``b`` of 0 pads the vector; callers discard them. Free
+    beyond the gathers: the per-position states already exist for the
+    output einsum.
     """
     DI = cfg.d_inner_
     W = cfg.conv_width
@@ -204,9 +219,10 @@ def mamba_apply(cfg: ModelConfig, p, x, h0=None, conv0=None,
             xp, jnp.asarray(length, jnp.int32), W - 1, axis=1)
     if state_at is None:
         return out, (h_last, conv_state)
+    at = jnp.asarray(state_at, jnp.int32)
     snaps = {
-        "h": jnp.stack([hs[0][:, b - 1] for b in state_at], axis=1),
-        "conv": jnp.stack([xp[:, b:b + W - 1] for b in state_at], axis=1),
+        "h": jnp.take(hs[0], jnp.maximum(at - 1, 0), axis=1),
+        "conv": jnp.take(xp, at[:, None] + jnp.arange(W - 1), axis=1),
     }
     return out, (h_last, conv_state), snaps
 

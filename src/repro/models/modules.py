@@ -70,6 +70,8 @@ class ModelConfig:
     attn_logit_softcap: float = 0.0
     final_logit_softcap: float = 0.0
     rope_theta: float = 10_000.0
+    use_rope: bool = True          # False: attention without position
+    # encoding (jamba's attention layers)
 
     # MoE
     n_experts: int = 0
@@ -86,6 +88,8 @@ class ModelConfig:
     d_inner: int = 0               # 0 -> 2*d_model
     conv_width: int = 4
     dt_rank: int = 0               # 0 -> d_model // 16
+    ssm_norms: bool = False        # RMS norms on dt, B and C before dt_proj
+    # and the scan (jamba's dt/b/c_layernorm)
 
     # hybrid (jamba)
     group_size: int = 1            # layers scanned per group

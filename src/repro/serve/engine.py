@@ -409,9 +409,10 @@ class ServeEngine:
         bucket × prefix-page bucket). ``tail=False`` is the cold miss: the
         same masked prefill-commit as ``_build_prefill_commit`` but ALSO
         returning the device payload a finish donates to the index — the
-        mamba end state, the page-boundary state snapshots (static slice
-        positions per bucket; free — the per-position states already exist
-        for the scan's output einsum), and the end logits the exact record
+        mamba end state, the boundary state snapshots at ``snap_at``
+        (tail-relative token counts, a traced vector whose length the
+        bucket fixes; free — the per-position states already exist for
+        the scan's output einsum), and the end logits the exact record
         stores. ``tail=True`` prefills ONLY the uncached tail of a partial
         hit: positions offset by the hit length, tail queries attending
         over the prefix K/V — read IN PLACE from the pool pages by the
@@ -424,9 +425,7 @@ class ServeEngine:
         mamba_ids = self._role_ids(True)
 
         def run(params, pool, prompt, length, offset, prefix_ids,
-                prefix_len, page_ids, lane, ssm_init):
-            S = prompt.shape[1]
-            boundaries = tuple(range(page_size, S + 1, page_size))
+                prefix_len, page_ids, lane, snap_at, ssm_init):
             kw = {}
             if tail and A.paged_kernel_enabled():
                 kw = dict(offset=offset, prefix_len=prefix_len,
@@ -440,10 +439,10 @@ class ServeEngine:
                               cfg, pool[f"b{i}"], prefix_ids)
                               for i in attn_ids})
             res = lm_prefill(cfg, params, self._inputs(params, prompt),
-                             length=length, state_at=boundaries or None,
-                             **kw)
+                             length=length,
+                             state_at=snap_at if mamba_ids else None, **kw)
             logits, pcache = res[0], res[1]
-            snaps = res[2] if boundaries else {}
+            snaps = res[2] if mamba_ids else {}
             pool = commit_prefill(cfg, pool, pcache["blocks"], lane,
                                   page_ids, page_size, length=length)
             end_ssm = {f"b{i}": pcache["blocks"][f"b{i}"]
@@ -453,14 +452,15 @@ class ServeEngine:
         if tail:
             def serve_tail_prefill(params, pool, prompt, length, offset,
                                    prefix_ids, prefix_len, page_ids, lane,
-                                   ssm_init):
+                                   snap_at, ssm_init):
                 return run(params, pool, prompt, length, offset, prefix_ids,
-                           prefix_len, page_ids, lane, ssm_init)
+                           prefix_len, page_ids, lane, snap_at, ssm_init)
             return jax.jit(serve_tail_prefill, donate_argnums=(1,))
 
-        def serve_prefill(params, pool, prompt, length, page_ids, lane):
+        def serve_prefill(params, pool, prompt, length, page_ids, lane,
+                          snap_at):
             return run(params, pool, prompt, length, None, None, None,
-                       page_ids, lane, None)
+                       page_ids, lane, snap_at, None)
         return jax.jit(serve_prefill, donate_argnums=(1,))
 
     def _build_hit_admit(self, fork: bool, has_ssm: bool):

@@ -13,11 +13,13 @@ Structure (vLLM/SGLang-style, page-granular):
 
   * a RADIX TREE over token-ID prefixes — each node owns a run of FULL
     pages (key length = pages * page_size) plus, for SSM-carrying configs,
-    the mamba (h, conv) state snapshot at every page boundary (captured
-    for free during prefill — the per-position states already exist for
-    the selective scan's output einsum). Divergence inside a node SPLITS
-    it at the page boundary; the node keeps its identity as the tail so
-    live pins (parent-chain walks) stay consistent.
+    the mamba (h, conv) state snapshot at SOME page boundaries: every
+    ``snapshot_stride``-th page of the prompt, and the last full page of
+    each stored prompt (captured during prefill — the per-position states
+    already exist for the selective scan's output einsum). Divergence
+    inside a node SPLITS it at the page boundary; the node keeps its
+    identity as the tail so live pins (parent-chain walks) stay
+    consistent.
   * EXACT RECORDS keyed by the full prompt: the partially-filled boundary
     page (if any), the end-of-prompt logits and mamba end state. An
     identical prompt re-admits with ZERO prefill — first token sampled
@@ -34,12 +36,19 @@ Structure (vLLM/SGLang-style, page-granular):
     free least-recently-used unpinned leaves / records until the incoming
     request's unshared tail fits. Pinned paths (live requests, records)
     are never reclaimed.
+  * A STATE BUDGET: SSM state held beside the pages (boundary snapshots
+    and the records' end states) stays under ``state_budget`` bytes —
+    each insert LRU-evicts records and unpinned leaves that hold state
+    until it fits, since page pressure alone never sees it.
 
 Partial hits resume at a page boundary: the session prefills ONLY the
 uncached tail (``lm_prefill(offset=, prefix=, ssm_init=)``) — exact
-position arithmetic for attention (RoPE is absolute; tail queries attend
-over the gathered prefix rows) and exact state resumption for the SSM
-recurrence. Numerics note: a partial-hit tail attends over the prefix
+position arithmetic for attention (positions are absolute; tail queries
+attend over the gathered prefix rows) and exact state resumption for the
+SSM recurrence. With SSM state the hit is rounded DOWN to the deepest
+boundary that holds a snapshot; the matched tokens above it are
+recomputed by the tail prefill and counted in ``resume_gap_tokens``.
+Numerics note: a partial-hit tail attends over the prefix
 rows AS STORED (dequantized under kv_cache_quant — the same bytes decode
 reads), so its tokens follow the serve-over-cache semantics rather than
 being bit-equal to a cold full prefill; EXACT hits re-read identical
@@ -69,6 +78,31 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+# SSM state held beside the cached pages (boundary snapshots + record end
+# states), in bytes; inserts LRU-evict past it.
+STATE_BUDGET_BYTES = 1 << 30
+# Boundary snapshots kept per cached token, at most (``snapshot_stride``).
+SNAPSHOT_BYTES_PER_TOKEN = 16 << 10
+
+
+def snapshot_stride(state_bytes: int, page_size: int) -> int:
+    """Pages between two kept boundary snapshots: the least power of two
+    that holds a cached prefix's snapshots to ``SNAPSHOT_BYTES_PER_TOKEN``
+    a token — 1 (every page) for a small state, 64 for jamba2-3b's 10 MB
+    at 16-token pages (PERF.md gives the reasoning); 0 without state."""
+    if not state_bytes:
+        return 0
+    k = 1
+    while state_bytes > k * page_size * SNAPSHOT_BYTES_PER_TOKEN:
+        k *= 2
+    return k
+
+
+def _state_bytes(tree) -> int:
+    import jax
+
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
 
 class IndexCorruption(RuntimeError):
     """A node's content no longer matches its sealed checksum: the index
@@ -97,7 +131,8 @@ class _Node:
         self.children: Dict[bytes, "_Node"] = {}
         self.key = key                  # int32 tokens, len == pages * P
         self.pages = pages              # physical page ids, logical order
-        self.snaps = snaps              # per-page boundary SSM state (|None)
+        self.snaps = snaps              # per-page boundary SSM state, None
+        # where none is kept
         self.ref = ref                  # pass-through pins (requests+records)
         self.tick = tick
         self.seal()
@@ -134,11 +169,19 @@ class Hit:
     pages: List[int]                    # shared pages, logical order
     ssm: Any                            # boundary state at hit_len (partial)
     record: Optional[_Record]           # exact hits only
+    gap: int = 0                        # matched tokens above hit_len given
+    # up for want of a snapshot (hit_len 0: a match below the first one)
 
 
 class PrefixCache:
-    def __init__(self, page_size: int, max_records: int = 256):
+    def __init__(self, page_size: int, max_records: int = 256,
+                 snapshot_stride: int = 0):
+        """``snapshot_stride``: 0 for a model without SSM state (hits are
+        never rounded); else the page stride of kept boundary snapshots
+        (module ``snapshot_stride``)."""
         self.page_size = page_size
+        self.snapshot_stride = snapshot_stride
+        self.state_budget = STATE_BUDGET_BYTES
         # records hold off-page device arrays (full-vocab logits + SSM end
         # state) that PAGE-pressure reclaim never sees, so the record map
         # is count-bounded with its own LRU — distinct-prompt traffic must
@@ -156,7 +199,29 @@ class PrefixCache:
                       "misses": 0, "hit_tokens": 0, "prompt_tokens": 0,
                       "inserted_pages": 0, "evicted_pages": 0,
                       "cow_forks": 0, "quarantines": 0,
-                      "demoted_pages": 0, "promoted_pages": 0}
+                      "demoted_pages": 0, "promoted_pages": 0,
+                      "resume_gap_tokens": 0,
+                      # gauges: SSM state held (snapshots + end states)
+                      "ssm_snapshots": 0, "ssm_snapshot_bytes": 0}
+
+    # -- snapshot policy -----------------------------------------------------
+    def snapshot_pages(self, offset: int, n_tokens: int) -> List[int]:
+        """Boundaries (page counts) past ``offset`` tokens whose snapshot
+        the index keeps for a prompt of ``n_tokens``: every
+        ``snapshot_stride``-th page and the last full page."""
+        if not self.snapshot_stride:
+            return []
+        P, k = self.page_size, self.snapshot_stride
+        last = n_tokens // P
+        return [b for b in range(offset // P + 1, last + 1)
+                if b % k == 0 or b == last]
+
+    def snapshot_slots(self, bucket: int) -> int:
+        """Length of the prefill's snapshot vector for a tail bucket: room
+        for ``snapshot_pages`` of any tail that fits it."""
+        if not self.snapshot_stride:
+            return 0
+        return bucket // (self.page_size * self.snapshot_stride) + 2
 
     # -- path helpers --------------------------------------------------------
     def _chain(self, node: _Node) -> List[_Node]:
@@ -201,6 +266,16 @@ class PrefixCache:
         node.seal()                     # legitimate mutation: re-checksum
         return head
 
+    def _deepest_snapshot(self, node: _Node, m: int) -> int:
+        """The deepest boundary at or above page ``m`` (where ``node``
+        ends) that holds a snapshot; 0 if none does."""
+        for n in reversed(self._chain(node)):
+            for i in range(len(n.snaps) - 1, -1, -1):
+                if n.snaps[i] is not None:
+                    return m - (len(n.snaps) - 1 - i)
+            m -= len(n.snaps)
+        return 0
+
     def _walk(self, tokens: np.ndarray, max_pages: int
               ) -> Tuple[_Node, List[int], int]:
         """Longest page-aligned match of ``tokens`` (up to ``max_pages``
@@ -234,10 +309,12 @@ class PrefixCache:
         """Longest cached prefix of ``tokens``. Exact records win (zero
         prefill); otherwise the longest page-aligned prefix STRICTLY
         shorter than the prompt, so the tail prefill always has >= 1 token
-        to produce the next-token logits from. Pure w.r.t. stats and LRU
-        ticks — those move on ``commit_hit`` when the request actually
-        admits, so a blocked queue head retrying every scheduling round
-        inflates nothing.
+        to produce the next-token logits from; with SSM state, rounded
+        down to the deepest boundary holding a snapshot (a match below the
+        first one is a hit of length 0: a miss that counts its gap). Pure
+        w.r.t. stats and LRU ticks — those move on ``commit_hit`` when the
+        request actually admits, so a blocked queue head retrying every
+        scheduling round inflates nothing.
 
         Every node on the returned path is checksum-verified as it is
         walked; a mismatch raises ``IndexCorruption`` — the scheduler's
@@ -255,19 +332,27 @@ class PrefixCache:
                         "record path node failed its checksum")
             return Hit(exact=True, hit_len=int(tokens.size), node=rec.node,
                        pages=self.path_pages(rec.node), ssm=None, record=rec)
-        node, pages, m = self._walk(tokens, (tokens.size - 1)
-                                    // self.page_size)
+        P = self.page_size
+        node, pages, m = self._walk(tokens, (tokens.size - 1) // P)
         if m == 0:
             return None
-        return Hit(exact=False, hit_len=m * self.page_size, node=node,
-                   pages=pages, ssm=node.snaps[-1] if node.snaps else None,
-                   record=None)
+        keep = self._deepest_snapshot(node, m) if self.snapshot_stride \
+            else m
+        if keep == 0:
+            return Hit(exact=False, hit_len=0, node=self.root, pages=[],
+                       ssm=None, record=None, gap=m * P)
+        if keep < m:
+            node, pages, _ = self._walk(tokens, keep)
+        return Hit(exact=False, hit_len=keep * P, node=node, pages=pages,
+                   ssm=node.snaps[-1], record=None, gap=(m - keep) * P)
 
     def commit_hit(self, hit: Optional[Hit], n_tokens: int) -> None:
         """Fold an ADMITTED request's lookup into stats + LRU ticks."""
         self.stats["lookups"] += 1
         self.stats["prompt_tokens"] += int(n_tokens)
-        if hit is None:
+        if hit is not None:
+            self.stats["resume_gap_tokens"] += hit.gap
+        if hit is None or not hit.hit_len:
             self.stats["misses"] += 1
             return
         self._touch(hit.node)
@@ -316,15 +401,22 @@ class PrefixCache:
         consumed = set()
         self._tick += 1
         if m < n_full:
-            # logical page j's physical id is req.pages[j]; snapshots are
-            # tail-relative to the request's prefill offset o: page j's
-            # boundary (j+1)*P maps to snap index (j+1) - o/P - 1.
-            o = ex["offset"]
+            # logical page j's physical id is req.pages[j]; the prefill
+            # captured a snapshot at each boundary of ``snap_pages`` (page
+            # counts, in order), so page j's boundary j+1 is snap index
+            # snap_pages.index(j+1), or has none
+            at = {b: i for i, b in enumerate(ex.get("snap_pages", ()))}
             new_pages = [req.pages[j] for j in range(m, n_full)]
-            snaps = [self._slice_snap(ex["snaps"], (j + 1) - o // P - 1)
-                     for j in range(m, n_full)]
+            snaps = [None] * (n_full - m)
+            if at:
+                with self._span("serve.snapshot_capture", pages=len(at)):
+                    snaps = [self._slice_snap(ex["snaps"], at[j + 1])
+                             if j + 1 in at else None
+                             for j in range(m, n_full)]
             child = _Node(node, tokens[m * P:n_full * P], new_pages, snaps,
                           self._tick)
+            for sn in snaps:
+                self._hold(sn, +1)
             node.children[tokens[m * P:(m + 1) * P].tobytes()] = child
             consumed.update(new_pages)
             self.stats["inserted_pages"] += len(new_pages)
@@ -340,15 +432,53 @@ class PrefixCache:
             self.records[kb] = _Record(
                 node=node, page=bpage, logits=ex["logits"],
                 end_ssm=ex["end_ssm"], n_tokens=S, tick=self._tick)
+            self._hold(ex["end_ssm"], +1)
             self.pin(node)              # the record pins its path
         self._touch(node)
+        if self.stats["ssm_snapshot_bytes"] > self.state_budget:
+            with self._span("serve.snapshot_evict"):
+                self._trim_state(alloc)
         return consumed
+
+    @staticmethod
+    def _span(name: str, **kw):
+        """A ``serve.*`` host span, on the device trace's clock."""
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(name, **kw)
+
+    def _hold(self, tree, sign: int) -> None:
+        """Count one snapshot or end state in (+1) or out (-1) of the
+        ``ssm_snapshots``/``ssm_snapshot_bytes`` gauges."""
+        n = _state_bytes(tree) if tree is not None else 0
+        if n:
+            self.stats["ssm_snapshots"] += sign
+            self.stats["ssm_snapshot_bytes"] += sign * n
+
+    def _trim_state(self, alloc) -> None:
+        """LRU-evict records and unpinned leaves that hold SSM state until
+        what the index holds fits ``state_budget``. (A victim may hold
+        pages the releasing request just donated: the index owns their
+        one ref, so eviction frees them and ``release`` leaves them.)"""
+        while self.stats["ssm_snapshot_bytes"] > self.state_budget:
+            cands = [(rec.tick, 0, kb) for kb, rec in self.records.items()
+                     if _state_bytes(rec.end_ssm)]
+            cands += [(n.tick, 1, n) for n in self._evictable_nodes()
+                      if any(sn is not None for sn in n.snaps)]
+            if not cands:
+                return
+            _, kind, victim = min(cands, key=lambda c: (c[0], c[1]))
+            if kind == 0:
+                self._evict_record(victim, alloc)
+            else:
+                self._evict_node(victim, alloc)
 
     def _evict_record(self, kb: bytes, alloc) -> bool:
         """Drop one record: unpin its path, release its boundary page —
         a host-resident boundary (negative id) frees its SLOT instead.
         Returns True iff a DEVICE page actually freed."""
         rec = self.records.pop(kb)
+        self._hold(rec.end_ssm, -1)
         self.unpin(rec.node)
         if rec.page is not None:
             if rec.page < 0:
@@ -459,6 +589,8 @@ class PrefixCache:
             raise IndexCorruption(
                 "reclaim victim failed its integrity check")
         node.parent.children.pop(kb)
+        for sn in node.snaps:
+            self._hold(sn, -1)
         freed = 0
         for p in node.pages:
             if p < 0:
@@ -611,6 +743,8 @@ class PrefixCache:
         while stack:
             n = stack.pop()
             stack.extend(n.children.values())
+            for sn in n.snaps:
+                self._hold(sn, -1)
             for p in n.pages:
                 if p < 0:
                     if self.swap is not None:
@@ -735,6 +869,7 @@ class PrefixCache:
                 pass
         self.root.children = {}
         self.records = {}
+        self.stats["ssm_snapshots"] = self.stats["ssm_snapshot_bytes"] = 0
         return freed
 
     def quarantine(self, alloc) -> int:

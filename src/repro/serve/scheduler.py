@@ -454,7 +454,7 @@ class Scheduler:
         state (``commit_hit`` does, at actual admission), so ranking the
         queue is free of side effects."""
         hit = self._lookup(req.effective_prompt)
-        if hit is None:
+        if hit is None or not hit.hit_len:
             return 2
         return 0 if hit.exact else 1
 

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import time
 from collections import Counter
+from contextlib import nullcontext
 from typing import Iterator, Optional, Sequence
 
 import jax
@@ -71,7 +72,7 @@ from repro.models.attention import paged_kernel_enabled, paged_kernel_override
 from .faults import FaultInjector, InjectedFault, corrupt_prefix_index
 from . import reasons
 from .paged_cache import pages_for
-from .prefix_cache import IndexCorruption, PrefixCache
+from .prefix_cache import IndexCorruption, PrefixCache, snapshot_stride
 from .sampling import logits_all_finite, sample_tokens
 from .scheduler import (TERMINAL, Request, RequestStatus, SamplingParams,
                         Scheduler)
@@ -88,6 +89,14 @@ def _default_bucket(S: int, floor: int = 8) -> int:
     while b < S:
         b <<= 1
     return b
+
+
+def _lane_state_bytes(cfg) -> int:
+    """Bytes of one lane's SSM state over every mamba layer (float32 h
+    and conv history): what one boundary snapshot holds."""
+    layers = cfg.n_groups * sum(r["mixer"] == "mamba"
+                                for r in block_roles(cfg))
+    return layers * 4 * cfg.d_inner_ * (cfg.ssm_state + cfg.conv_width - 1)
 
 
 def _raw_key(key):
@@ -254,9 +263,11 @@ class ServeSession:
         self.n_pages = n_pages
         if prefix_cache is None:
             prefix_cache = engine.prefix_cache
-        self.prefix = PrefixCache(page_size) if prefix_cache else None
         self._has_ssm = any(r["mixer"] == "mamba"
                             for r in block_roles(engine.cfg))
+        self.prefix = PrefixCache(page_size, snapshot_stride=snapshot_stride(
+            _lane_state_bytes(engine.cfg), page_size)) \
+            if prefix_cache else None
         self.faults = faults if faults is not None else FaultInjector.from_env()
         self.audit_mode = audit
         self._clock = clock if clock is not None \
@@ -651,7 +662,7 @@ class ServeSession:
         traced mask), scatter the masked rows into the request's tail
         pages, and — when the prefix index is on — capture the device
         payload a finish donates to it (end logits, mamba end state,
-        page-boundary state snapshots). A partial hit threads the position
+        kept boundary snapshots). A partial hit threads the position
         offset, the gathered prefix K/V pages, and the boundary SSM state
         through ``lm_prefill`` so the tail is computed exactly as a
         continuation of the cached prefix."""
@@ -684,6 +695,10 @@ class ServeSession:
              paged_kernel_enabled()),
             lambda: self.engine._build_pfx_prefill(self.page_size,
                                                    tail=ppb > 0))
+        snap_pages = self.prefix.snapshot_pages(o, o + T)
+        snap_at = np.zeros((self.prefix.snapshot_slots(bucket),), np.int32)
+        snap_at[:len(snap_pages)] = [b * self.page_size - o
+                                     for b in snap_pages]
         ssm_init = {}
         if ppb > 0:
             args = (jnp.asarray(o, jnp.int32), jnp.asarray(prefix_ids),
@@ -692,14 +707,18 @@ class ServeSession:
                 ssm_init = req.hit.ssm
         else:
             args = ()
-        logits, self._pool, end_ssm, snaps = pfn(
-            self.engine.params, self._take_pool(),
-            jnp.asarray(padded[None]), jnp.asarray(T, jnp.int32), *args,
-            jnp.asarray(page_ids), jnp.asarray(req.lane, jnp.int32),
-            *((ssm_init,) if ppb > 0 else ()))
+        resume = TraceAnnotation("serve.snapshot_restore", tokens=o,
+                                 gap=req.hit.gap) \
+            if ppb > 0 and self._has_ssm else nullcontext()
+        with resume:
+            logits, self._pool, end_ssm, snaps = pfn(
+                self.engine.params, self._take_pool(),
+                jnp.asarray(padded[None]), jnp.asarray(T, jnp.int32), *args,
+                jnp.asarray(page_ids), jnp.asarray(req.lane, jnp.int32),
+                jnp.asarray(snap_at), *((ssm_init,) if ppb > 0 else ()))
         req.cache_extras = {"tokens": np.array(eff, np.int32), "offset": o,
                             "logits": logits, "end_ssm": end_ssm,
-                            "snaps": snaps,
+                            "snaps": snaps, "snap_pages": snap_pages,
                             # exact records promise bit-identity with a
                             # COLD run; a kv-quant tail prefill computes
                             # over DEQUANTIZED prefix rows, so its end

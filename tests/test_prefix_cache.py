@@ -1,6 +1,6 @@
 """Prefix-cache subsystem validation.
 
-Five layers, mirroring the PR contract:
+Six layers, mirroring the PR contract:
   1. ACCEPTANCE — cache-hit generation is BIT-IDENTICAL (greedy) to a
      cold-cache run of the same prompt across dense, packed, kv-quant,
      ssm and hybrid configs, with zero prefill compiles on the hit path;
@@ -17,7 +17,10 @@ Five layers, mirroring the PR contract:
      eviction order, refcount-never-negative (hypothesis-based);
   5. satellites — emission-before-decode schedule (TTFT = prefill, the
      tightened pages_for bound), CachePool donation-safety + limit
-     plumbing.
+     plumbing;
+  6. SSM snapshots — kept every ``snapshot_stride`` pages and at each
+     stored prompt's last full page, hits rounded down to one (the
+     tokens given up counted), the state held bounded.
 """
 import types
 
@@ -29,10 +32,11 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import get_smoke
-from repro.models import lm_init
+from repro.models import cache_init, lm_init
 from repro.serve import (CachePool, PageAllocator, PrefixCache, Request,
                          RequestStatus, SamplingParams, Scheduler,
                          ServeEngine, pages_for)
+from repro.serve.session import _lane_state_bytes
 
 RNG = np.random.default_rng(0)
 
@@ -589,3 +593,140 @@ def test_cache_pool_fifo_eviction_order_and_engine_limit():
     params, _ = lm_init(jax.random.PRNGKey(0), cfg)
     eng = ServeEngine(cfg, params, max_len=16, cache_pool_limit=3)
     assert eng._caches.limit == 3
+
+
+# ---------------------------------------------------------------------------
+# 6. SSM snapshots the index can hold: one every ``snapshot_stride`` pages
+#    plus the last full page of each stored prompt, hits rounded down to
+#    the deepest one, and the state held kept under its budget
+# ---------------------------------------------------------------------------
+def _stride_engine(monkeypatch, cfg, stride, page=4, max_len=48):
+    """An engine whose sessions keep a snapshot every ``stride`` pages:
+    the per-token allowance is set so ``snapshot_stride`` derives it from
+    the model's own lane state."""
+    import repro.serve.prefix_cache as PC
+
+    per_token = _lane_state_bytes(cfg) // (stride * page)
+    monkeypatch.setattr(PC, "SNAPSHOT_BYTES_PER_TOKEN", per_token)
+    assert PC.snapshot_stride(_lane_state_bytes(cfg), page) == stride
+    params, _ = lm_init(jax.random.PRNGKey(0), cfg)
+    return ServeEngine(cfg, params, max_len=max_len, prefix_cache=True)
+
+
+def _boundary_snapshots(cache) -> int:
+    n, stack = 0, [cache.root]
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children.values())
+        n += sum(s is not None for s in node.snaps)
+    return n
+
+
+def _hybrid_mqa():
+    """jamba's block without experts: one KV head, no RoPE, dt/B/C norms."""
+    return get_smoke("jamba-1.5-large-398b").scaled(
+        n_experts=0, top_k=0, n_kv_heads=1, use_rope=False, ssm_norms=True,
+        dtype=jnp.float32)
+
+
+def test_partial_hit_rounded_to_a_stride_snapshot_matches_cold(monkeypatch):
+    """Two prompts share 6 pages; snapshots sit at pages 4 and 7 of the
+    first, so the second resumes from page 4 and recomputes pages 5-6:
+    its prefill logits and tokens are those of a cold full prefill."""
+    cfg = _hybrid_mqa()
+    engine = _stride_engine(monkeypatch, cfg, stride=4)
+    rng = np.random.default_rng(11)
+    p1 = _prompt(cfg, 30, rng)
+    p2 = p1.copy()
+    p2[24:] = (p2[24:] + 1) % cfg.vocab_size
+    ref2 = _ref(engine, p2, 5)
+    cold_logits, _ = engine._prefill(engine.params,
+                                     {"tokens": jnp.asarray(p2[None])},
+                                     cache_init(cfg, 1, engine.max_len)[0])
+    with engine.session(lanes=2, page_size=4, segment=2) as sess:
+        assert sess.prefix.snapshot_stride == 4
+        sess.submit(p1, SamplingParams(max_tokens=5)).result()
+        out2 = np.asarray(sess.submit(p2, SamplingParams(max_tokens=5))
+                          .result())
+        st = sess.prefix.stats
+        assert (st["partial_hits"], st["hit_tokens"],
+                st["resume_gap_tokens"]) == (1, 16, 8)
+        rec = sess.prefix.records[p2.tobytes()]
+        _assert_conserved(sess)
+    np.testing.assert_array_equal(out2, ref2)
+    got, want = np.asarray(rec.logits)[0, -1], np.asarray(cold_logits)[0, -1]
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def test_snapshots_held_are_bounded_by_the_stride(monkeypatch):
+    """After N prompts of L tokens the index holds at most
+    N * (L // (page * K) + 1) boundary snapshots, and its gauges count
+    them, and the records' end states, to the byte."""
+    import repro.serve.prefix_cache as PC
+
+    cfg = get_smoke("falcon-mamba-7b")
+    engine = _stride_engine(monkeypatch, cfg, stride=4)
+    rng = np.random.default_rng(12)
+    N, L = 4, 37
+    with engine.session(lanes=2, page_size=4, segment=2) as sess:
+        for _ in range(N):
+            sess.submit(_prompt(cfg, L, rng), SamplingParams(max_tokens=3))
+        sess.run_until_idle()
+        cache = sess.prefix
+        held = _boundary_snapshots(cache)
+        assert 0 < held <= N * (L // (4 * 4) + 1)
+        ends = [r.end_ssm for r in cache.records.values()]
+        assert cache.stats["ssm_snapshots"] == held + len(ends)
+        nodes, stack = [], [cache.root]
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            nodes += [s for s in n.snaps if s is not None]
+        assert cache.stats["ssm_snapshot_bytes"] == sum(
+            PC._state_bytes(t) for t in nodes + ends)
+
+
+def test_resume_gap_tokens_counts_what_a_hit_gave_up(monkeypatch):
+    """A match below the first kept snapshot is a miss that still counts
+    its matched tokens as given up; an attention-only model never rounds."""
+    cfg = get_smoke("falcon-mamba-7b")
+    engine = _stride_engine(monkeypatch, cfg, stride=4)
+    rng = np.random.default_rng(13)
+    p1 = _prompt(cfg, 10, rng)                  # snapshot at page 2 only
+    p2 = np.concatenate([p1[:4], _prompt(cfg, 6, rng)])   # shares page 1
+    with engine.session(lanes=2, page_size=4, segment=2) as sess:
+        sess.submit(p1, SamplingParams(max_tokens=3)).result()
+        sess.submit(p2, SamplingParams(max_tokens=3)).result()
+        st = sess.prefix.stats
+        assert (st["misses"], st["partial_hits"], st["hit_tokens"],
+                st["resume_gap_tokens"]) == (2, 0, 0, 4)
+    dense, dcfg = _engine()
+    with dense.session(lanes=2, page_size=4) as sess:
+        assert sess.prefix.snapshot_stride == 0
+        sess.submit(p1 % dcfg.vocab_size,
+                    SamplingParams(max_tokens=3)).result()
+        sess.submit(p2 % dcfg.vocab_size,
+                    SamplingParams(max_tokens=3)).result()
+        st = sess.prefix.stats
+        assert (st["partial_hits"], st["hit_tokens"],
+                st["resume_gap_tokens"]) == (1, 4, 0)
+
+
+def test_state_budget_evicts_lru_state_with_its_entry(monkeypatch):
+    """Distinct prompts past the budget: inserts LRU-evict records and
+    leaves that hold state, so what the index holds stays under it, and
+    pages and pins stay consistent."""
+    cfg = get_smoke("falcon-mamba-7b")
+    engine = _stride_engine(monkeypatch, cfg, stride=2)
+    rng = np.random.default_rng(14)
+    with engine.session(lanes=2, page_size=4, segment=2) as sess:
+        one = _lane_state_bytes(cfg)
+        sess.prefix.state_budget = 5 * one
+        for _ in range(6):
+            sess.submit(_prompt(cfg, 21, rng), SamplingParams(max_tokens=3))
+            sess.run_until_idle()
+            assert sess.prefix.stats["ssm_snapshot_bytes"] <= 5 * one
+        assert sess.prefix.stats["evicted_pages"] > 0
+        sess.prefix.audit(sess.sched.alloc)
+        _assert_conserved(sess)

@@ -103,3 +103,60 @@ def test_paged_prefix_compiles_at_1024_prefix_512_tail(one_chip):
         sds((T, KVP, HD), CFG.dtype), sds((T, KVP, HD), CFG.dtype),
         pool, pool, sds((npp,), jnp.int32), scalar, scalar, scalar,
         n_rep=HP // KVP, chunk=CFG.attn_chunk)
+
+
+# jamba2-3b (AI21-Jamba2-3B) at its published widths: d 2560, Mamba d_inner
+# 5120, state 16, dt rank 160; 20 query heads of 128 laid out as 32 over
+# one KV head, which attention holds as two copies (16 query heads each);
+# FFN 8192; 32 lanes x 6,144 positions, two attention layers
+J_D, J_DI, J_FF, J_LANES, J_LEN = 2560, 5120, 8192, 32, 6144
+J_HP, J_KV = 32, 2
+J_GEMVS = {"in_x": (J_D, J_DI), "x_proj": (J_DI, 160 + 2 * 16),
+           "dt_proj": (160, J_DI), "out_proj": (J_DI, J_D),
+           "wqkv": (J_D, (J_HP + 2) * HD), "wo": (J_HP * HD, J_D),
+           "wgu": (J_D, 2 * J_FF), "wd": (J_FF, J_D)}
+
+
+@pytest.mark.parametrize("name", sorted(J_GEMVS))
+def test_jamba_gemv_compiles(one_chip, name):
+    K, N = J_GEMVS[name]
+    x = jax.ShapeDtypeStruct((J_LANES, K), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((-(-K // 32), N), jnp.uint32, sharding=one_chip)
+    assert "tpu_custom_call" in _compiled_text(packed_xnor_gemv, x, w,
+                                               k_valid=K)
+
+
+def _jamba_pool(one_chip, layers=()):
+    n_pages = J_LANES * (J_LEN // PAGE) + 1
+    return jax.ShapeDtypeStruct(layers + (n_pages, PAGE, J_KV, HD),
+                                jnp.bfloat16, sharding=one_chip)
+
+
+def test_jamba_mqa_paged_decode_compiles(one_chip):
+    """One KV head held twice: each copy's rows stage as a tile-aligned
+    (page, 2, hd) block (a single head's (page, 1, hd) is refused)."""
+    pool = _jamba_pool(one_chip, layers=(2,))
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q = sds((J_LANES, J_KV, J_HP // J_KV, HD), jnp.bfloat16)
+    assert "tpu_custom_call" in _compiled_text(
+        paged_flash_decode, q, pool, pool, sds((J_LANES, J_LEN // PAGE)),
+        sds((J_LANES,)), sds(()), chunk=512)
+
+
+def test_jamba_mqa_paged_prefix_compiles(one_chip):
+    """A doc-qa hit: 4,096 cached positions and a 1,024-row tail, 16 query
+    heads a KV copy, in 512-row chunks (1,024 overruns scoped VMEM)."""
+    pool = _jamba_pool(one_chip)
+    T, npp = 1024, 4096 // PAGE
+
+    def sds(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert "tpu_custom_call" in _compiled_text(
+        paged_prefix_attention, sds((J_HP, T, HD), jnp.bfloat16),
+        sds((T, J_KV, HD), jnp.bfloat16), sds((T, J_KV, HD), jnp.bfloat16),
+        pool, pool, sds((npp,)), sds(()), sds(()), sds(()),
+        n_rep=J_HP // J_KV, chunk=512)
